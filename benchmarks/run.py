@@ -13,6 +13,7 @@ import time
 from benchmarks import ablation_prediction, engine_throughput, fig3_convergence
 from benchmarks import fig4_class_ratio, kernel_bench, roofline_report
 from benchmarks import table1_connection_rate
+from repro.launch.compile_cache import use_compile_cache
 
 SECTIONS = {
     "kernels": kernel_bench.main,
@@ -29,6 +30,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="", help="comma-separated section names")
     args, _ = ap.parse_known_args()
+    use_compile_cache()
     names = [n for n in args.only.split(",") if n] or list(SECTIONS)
     for name in names:
         print(f"\n===== {name} =====")

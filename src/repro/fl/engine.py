@@ -111,7 +111,7 @@ from repro.fl.rounds import (
     metrics_to_records,
 )
 from repro.models import build_model
-from repro.sharding import SHARD_MAP_NO_CHECK, TRAIN_RULES, resolve_pspec, shard_map, split_params
+from repro.sharding import TRAIN_RULES, resolve_pspec, split_params
 from repro.utils import tree_bytes
 
 ScenarioLike = Union[str, TrafficConfig]
@@ -323,12 +323,12 @@ class ExperimentEngine:
                     warm=self.warmup_enabled,
                 )
 
-            return shard_map(
+            return jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(row, data_spec, row, row, row, row, rep),
                 out_specs=(row, row),
-                **SHARD_MAP_NO_CHECK,
+                check_vma=False,
             )(states, datas, scns, strat_idx, agg_idx, data_idx, flags)
 
         return jax.jit(fn, donate_argnums=(0,))

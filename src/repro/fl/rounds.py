@@ -437,15 +437,25 @@ def make_warmup(loss_fn, fl: FLConfig, param_spec):
     def warmup(state: RoundState, data: RoundData, data_idx=None) -> RoundState:
         bs = fl.batch_size
         params = unflatten_from_vector(state.params, param_spec)
-        _, vecs = one_step(
-            params,
-            _row(data.images, data_idx)[:, :bs],
-            _row(data.labels, data_idx)[:, :bs],
-            fold_in_str(state.key, "warmup"),
+        # slice each client's first batch BEFORE the lane's row gather: a
+        # gather of whole rows materializes every client's full shard per lane
+        axis = 1 if data_idx is None else 2
+        first = lambda leaf: _row(
+            jax.lax.slice_in_dim(leaf, 0, bs, axis=axis), data_idx
         )
-        sketches = jax.vmap(
-            lambda v: apply_sketch(v, state.sketch_sign, fl.sketch_dim)
-        )(vecs)
+        images, labels = first(data.images), first(data.labels)
+        keys = jax.random.split(fold_in_str(state.key, "warmup"), images.shape[0])
+
+        def sketch_one(x):
+            vec = one_step(params, x[0][None], x[1][None], x[2][None])[1][0]
+            return apply_sketch(vec, state.sketch_sign, fl.sketch_dim)
+
+        # all N clients train and sketch in chunks of the cohort width, so
+        # the bootstrap's peak memory is a round's: neither N clients'
+        # activations nor their (N, P) update vectors exist at once
+        sketches = jax.lax.map(
+            sketch_one, (images, labels, keys), batch_size=fl.n_select
+        )
         k_km = fold_in_str(jax.random.fold_in(state.key, 0), "kmeans")
         clusters, _ = kmeans_cluster(sketches, k_km, fl.num_clusters)
         return state._replace(

@@ -18,10 +18,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _reduce_kernel(w_ref, u_ref, o_ref):
+def mxu_precision(interpret: bool):
+    """Dot precision of the FL reductions' fp32 contract.
+
+    Compiled for a TPU, Mosaic contracts an f32 dot in one bf16 MXU pass
+    (~2**-9 relative) unless asked for HIGHEST.  Interpret mode runs on
+    the CPU, where an f32 dot is fp32 anyway: the default there keeps
+    XLA's fusion of the kernel body, and its bitwise parity with the
+    refs, as it was.
+    """
+    return None if interpret else jax.lax.Precision.HIGHEST
+
+
+def _reduce_kernel(precision, w_ref, u_ref, o_ref):
     # w: (1, K), u: (K, bp) -> o: (1, bp)
     o_ref[...] = jnp.dot(
         w_ref[...], u_ref[...].astype(jnp.float32),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 
@@ -41,7 +54,7 @@ def fedavg_reduce(
     w2 = weights.astype(jnp.float32).reshape(1, K)
     Pp = P + pp
     out = pl.pallas_call(
-        _reduce_kernel,
+        functools.partial(_reduce_kernel, mxu_precision(interpret)),
         grid=(Pp // block_p,),
         in_specs=[
             pl.BlockSpec((1, K), lambda j: (0, 0)),

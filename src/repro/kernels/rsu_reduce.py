@@ -44,10 +44,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fedavg_reduce import mxu_precision
+
 _LANE = 128  # TPU lane width: minimum last-dim tile
 
 
-def _seg_kernel(w_ref, rid_ref, u_ref, part_ref, mass_ref, acc_ref, macc_ref):
+def _seg_kernel(precision, w_ref, rid_ref, u_ref, part_ref, mass_ref, acc_ref,
+                macc_ref):
     """One grid step: (p-tile, k-block).  Scratch persists across k."""
     kb = pl.program_id(1)
     first_p = pl.program_id(0) == 0
@@ -70,6 +73,7 @@ def _seg_kernel(w_ref, rid_ref, u_ref, part_ref, mass_ref, acc_ref, macc_ref):
     acc_ref[...] += jax.lax.dot_general(
         m, u_ref[...].astype(jnp.float32),
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 
@@ -118,7 +122,7 @@ def rsu_reduce(
     rid2 = jnp.pad(rid.astype(jnp.int32), (0, pad_k)).reshape(-1, 1)
     Kp, Pp = K + pad_k, P + pad_p
     partials, mass = pl.pallas_call(
-        _seg_kernel,
+        functools.partial(_seg_kernel, mxu_precision(interpret)),
         grid=(Pp // block_p, Kp // bk),
         in_specs=[
             pl.BlockSpec((bk, 1), lambda p, k: (k, 0)),

@@ -101,8 +101,10 @@ def _chain_kernel(n_clients, n_rsu, n_steps, dt, horizon_s, want_rid,
 
     # ---- RSU attachment: masked argmin over the (bn, Rp) ring distances.
     rp = mask_ref.shape[1]
+    # Mosaic has only an integer iota; the cast is exact for lane ids < 2**24
     rsu_pos = (
-        jax.lax.broadcasted_iota(jnp.float32, (1, rp), 1) * s["rsu_spacing_m"]
+        jax.lax.broadcasted_iota(jnp.int32, (1, rp), 1).astype(jnp.float32)
+        * s["rsu_spacing_m"]
     )
     d = jnp.abs(pos - rsu_pos)  # (bn, Rp); broadcast against (1, Rp)
     d = jnp.minimum(d, s["ring_length_m"] - d)

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fedavg_reduce import mxu_precision
+
 
 def _rule_math(agg, delta, p, m, v, eta, beta1, beta2, tau):
     """Branchless per-tile moment rules + parameter step (factored out of
@@ -64,8 +66,8 @@ def _rule_math(agg, delta, p, m, v, eta, beta1, beta2, tau):
     return p + step, m_new, v_new
 
 
-def _update_kernel(eta, beta1, beta2, tau, s_ref, w_ref, u_ref, p_ref,
-                   m_ref, v_ref, po_ref, mo_ref, vo_ref):
+def _update_kernel(eta, beta1, beta2, tau, precision, s_ref, w_ref, u_ref,
+                   p_ref, m_ref, v_ref, po_ref, mo_ref, vo_ref):
     # s: (1, 2) traced scalars [global agg index, round]; w: (1, K);
     # u: (K, bp) in ANY float dtype (bf16 update rows upcast in-tile, the
     # dot accumulates fp32); p/m/v: (1, bp) fp32 -> the params output
@@ -73,6 +75,7 @@ def _update_kernel(eta, beta1, beta2, tau, s_ref, w_ref, u_ref, p_ref,
     agg = s_ref[0, 0]
     delta = jnp.dot(
         w_ref[...], u_ref[...].astype(jnp.float32),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
     po, mo, vo = _rule_math(
@@ -120,7 +123,8 @@ def server_update(
         [agg_idx.astype(jnp.float32), rnd.astype(jnp.float32)]
     ).reshape(1, 2)
     Pp = P + pp
-    kernel = functools.partial(_update_kernel, eta, beta1, beta2, tau)
+    kernel = functools.partial(_update_kernel, eta, beta1, beta2, tau,
+                               mxu_precision(interpret))
     p2, m2, v2 = pl.pallas_call(
         kernel,
         grid=(Pp // block_p,),

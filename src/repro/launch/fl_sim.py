@@ -34,6 +34,7 @@ from repro.core.scenarios import SCENARIOS, scenario_config
 from repro.core.selection import STRATEGIES
 from repro.fl.aggregators import AGGREGATOR_ORDER
 from repro.fl.simulation import FLSimulation, time_to_accuracy
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run_experiment(
@@ -138,6 +139,7 @@ def main(argv=None):
             f"{', '.join(FLConfig.SUPPORTED_DTYPES)}"
         )
 
+    use_compile_cache()
     result = run_experiment(
         args.dataset, args.strategy, args.rounds, args.connection_rate,
         args.classes_per_client, args.num_clients, args.seed,

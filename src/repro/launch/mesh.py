@@ -8,17 +8,25 @@ importing this module must never touch jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes.  JAX 0.9 makes mesh axes Explicit
+    by default, which types every array's sharding: the engine's metrics
+    slice-back and GSPMD-sharded models then fail to trace on them."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (same axis names as single pod)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_grid_mesh(num_devices: int | None = None):
@@ -31,4 +39,4 @@ def make_grid_mesh(num_devices: int | None = None):
     1-device host the engine falls back to the plain vmapped program.
     """
     n = num_devices if num_devices is not None else len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _mesh((n,), ("data",))

@@ -12,6 +12,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import dense_init, zeros_init
 from repro.sharding import Param
@@ -68,8 +69,10 @@ def cnn_logits(params, cfg, images):
             x, conv["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
         )
         x = jax.nn.relu(x + conv["b"][None, None, None, :])
+        # a NumPy init value keeps this the differentiable max-pool: a
+        # traced one turns it into a generic reduce_window, which has no VJP
         x = jax.lax.reduce_window(
-            x, jnp.asarray(-jnp.inf, x.dtype), jax.lax.max,
+            x, np.array(-np.inf, x.dtype), jax.lax.max,
             (1, 2, 2, 1), (1, 2, 2, 1), "VALID"
         )
     x = x.reshape(x.shape[0], -1)

@@ -31,8 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init
-from repro.sharding import SHARD_MAP_NO_CHECK as _SHARD_MAP_NO_CHECK
-from repro.sharding import act_shard, shard_map
+from repro.sharding import act_shard
 from repro.sharding.context import _STATE as _SHARD_STATE
 
 
@@ -206,11 +205,11 @@ def _moe_shard_map(p, x, cfg, mesh, capacity_factor: float = 1.25):
             aux = jax.lax.pmean(aux, data_axes)
         return y.reshape(Bl, Sl, dl).astype(xl.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, None), wg_spec, wg_spec, wd_spec, batch_spec),
         out_specs=(batch_spec, P()),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
     return y, aux

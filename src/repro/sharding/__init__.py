@@ -1,18 +1,4 @@
 """Logical-axis sharding rules (MaxText-style) with divisibility fallback."""
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.6
-SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
-
 from repro.sharding.rules import (
     TRAIN_RULES,
     SERVE_RULES,
@@ -27,8 +13,6 @@ from repro.sharding.rules import (
 from repro.sharding.context import activation_sharding, act_shard
 
 __all__ = [
-    "shard_map",
-    "SHARD_MAP_NO_CHECK",
     "TRAIN_RULES",
     "SERVE_RULES",
     "SERVE_FSDP_RULES",

@@ -12,6 +12,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import fedavg_reduce, pairwise_cosine, ref, ssd_scan, swa_decode
+from repro.kernels.rsu_reduce import rsu_reduce
+from repro.kernels.server_update import server_update, server_update_buffered
 
 pytestmark = pytest.mark.tier1
 
@@ -152,3 +154,30 @@ def test_ssd_scan_matches_training_path():
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2, dtype=np.float32),
                                atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=5e-4, rtol=5e-4)
+
+
+# the FL reductions as compiled for a TPU: (K=8, P=256) fp32 rows, 4 RSUs
+_HP = dict(eta=0.1, beta1=0.9, beta2=0.99, tau=1e-3)
+_FL_REDUCTIONS = {
+    "fedavg_reduce": lambda u, w, i: fedavg_reduce(u, w, block_p=256),
+    "rsu_reduce": lambda u, w, i: rsu_reduce(u, w, i % 4, 4, block_p=256,
+                                             block_k=8),
+    "server_update": lambda u, w, i: server_update(
+        u, w, u[0], u[1], u[2], i[0], i[1], block_p=256, **_HP),
+    "server_update_buffered": lambda u, w, i: server_update_buffered(
+        u, w, u, w, u[0], u[1], u[2], i[0], i[1], True, block_p=256, **_HP),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FL_REDUCTIONS))
+def test_fl_reductions_contract_in_fp32_on_the_chip(name):
+    """Every dot of the compiled FL reductions asks for HIGHEST precision:
+    Mosaic's default contracts an f32 dot in one bf16 MXU pass, which put
+    the kernels ~1e-3 off their fp32 refs on a TPU v5e.  Tracing builds
+    the kernel body without compiling it, so this runs on the CPU."""
+    u = jnp.ones((8, 256), jnp.float32)
+    w, i = jnp.ones((8,), jnp.float32), jnp.arange(8, dtype=jnp.int32)
+    text = str(jax.make_jaxpr(_FL_REDUCTIONS[name])(u, w, i))
+    dots = text.count("dot_general[")
+    assert dots >= 1
+    assert text.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == dots

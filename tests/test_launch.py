@@ -1,10 +1,16 @@
-"""Launch layer: input specs, cache specs, trip counts, HLO analysis."""
+"""Launch layer: input specs, cache specs, trip counts, HLO analysis,
+the persistent compile cache's placement."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.config import INPUT_SHAPES, TrainConfig, shape_by_name
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import CHECKOUT_CACHE_DIR
 from repro.launch.hlo_analysis import parse_hlo, scope_trip_counts
 from repro.launch.steps import (
     TrainState,
@@ -197,3 +203,47 @@ def test_all_input_shapes_registered():
     assert set(INPUT_SHAPES) == {"train_4k", "prefill_32k", "decode_32k", "long_500k"}
     s = shape_by_name("long_500k")
     assert s.seq_len == 524_288 and s.global_batch == 1 and s.mode == "decode"
+
+
+_CACHE_PROBE = (
+    "from repro.launch.compile_cache import use_compile_cache\n"
+    "print(use_compile_cache())\n"
+    "import jax\n"
+    "jax.jit(lambda x: x * 3.0 + 1.0)(2.0).block_until_ready()\n"
+)
+
+
+def _run_cache_probe(env_dir):
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu", JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.strip().splitlines()[-1]
+
+
+def _entries(path):
+    return set(os.listdir(path)) if os.path.isdir(path) else set()
+
+
+def test_compile_cache_follows_env_dir(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only place entries land."""
+    before = _entries(CHECKOUT_CACHE_DIR)
+    assert _run_cache_probe(tmp_path) == str(tmp_path)
+    assert _entries(tmp_path)
+    assert _entries(CHECKOUT_CACHE_DIR) == before
+
+
+def test_compile_cache_defaults_to_checkout():
+    """Unset, the cache is the checkout's gitignored .jax_cache, a path
+    that depends on nothing but where this checkout lies."""
+    root = os.path.normpath(os.path.join(os.path.dirname(__file__), ".."))
+    assert CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    assert _run_cache_probe(None) == CHECKOUT_CACHE_DIR
+    assert _entries(CHECKOUT_CACHE_DIR)

@@ -15,11 +15,13 @@ _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro.config import ModelConfig
     from repro.models.moe import init_moe, _moe_gspmd, _moe_shard_map
     from repro.sharding import split_params
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for E in (4, 2):  # expert-sharded and ff-sliced cases
         cfg = ModelConfig(name="m", family="moe", num_layers=1, d_model=64,
                           num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=64,

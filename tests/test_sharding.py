@@ -16,15 +16,8 @@ from repro.sharding import (
     split_params,
 )
 
-def _abstract_mesh(shape, names):
-    try:  # jax >= 0.5 signature: (axis_sizes, axis_names)
-        return AbstractMesh(shape, names)
-    except TypeError:  # jax 0.4.x signature: one ((name, size), ...) tuple
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_resolution():
