@@ -27,7 +27,10 @@ import re
 import sys
 import time
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
+ROOT = os.path.dirname(os.path.abspath(__file__))
+for _p in (os.path.join(ROOT, "src"), ROOT):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -47,6 +50,8 @@ from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_grid_mesh  # noqa: E402
 from repro.utils import tree_bytes  # noqa: E402
 
+from bench import trace  # noqa: E402
+
 EPS32 = float(np.finfo(np.float32).eps)
 
 
@@ -63,7 +68,9 @@ class Phase:
     scenarios: tuple
     rounds: int
     warmup: bool
-    # the jitted kernel wrappers whose pallas_call the compiled grid holds
+    # the jit wrappers whose pallas_call the compiled grid holds: any
+    # wrapper on the op path of a kernel bench.trace.kernel_instructions
+    # finds (server_update_buffered's pallas_call sits in server_update's)
     kernels: tuple
 
     def engine(self, mesh=None) -> ExperimentEngine:
@@ -170,19 +177,6 @@ class AheadOfTime:
                         f"carry {carry_b}, total {data_b + carry_b}")
 
 
-def kernels_in(hlo: str):
-    """-> (number of tpu_custom_call ops, Counter of the jit wrappers they
-    sit in, read from each op's op_name)."""
-    n, names = 0, collections.Counter()
-    for line in hlo.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
-            continue
-        n += 1
-        m = re.search(r'op_name="([^"]*)"', line)
-        names.update(set(re.findall(r"jit\((\w+)\)", m.group(1) if m else "")))
-    return n, names
-
-
 def check_metrics(phase: str, metrics, eval_every: int) -> None:
     """Every leaf finite in every lane, except test_acc / test_loss on the
     rounds that do not evaluate, which hold NaN by design."""
@@ -224,8 +218,11 @@ def run_phase(phase: Phase) -> None:
                         f", output {mem.output_size_in_bytes}")
     for r in (res, res2):
         check_metrics(phase.name, r.metrics, phase.rounds)
-    n, names = kernels_in(aot.compiled.as_text())
-    say(phase.name, f"tpu_custom_call ops in the grid program: {n} "
+    hlo = aot.compiled.as_text()
+    kernels, paths = trace.kernel_instructions(hlo), trace.op_paths(hlo)
+    names = collections.Counter(w for k in kernels
+                                for w in set(re.findall(r"jit\((\w+)\)", paths[k])))
+    say(phase.name, f"tpu_custom_call ops in the grid program: {len(kernels)} "
                     f"({', '.join(f'{k} {names[k]}' for k in phase.kernels)})")
     missing = [k for k in phase.kernels if not names[k]]
     if missing:

@@ -113,6 +113,7 @@ from repro.fl.rounds import (
 from repro.models import build_model
 from repro.sharding import TRAIN_RULES, resolve_pspec, split_params
 from repro.utils import tree_bytes
+from repro.utils.tracing import span, stage
 
 ScenarioLike = Union[str, TrafficConfig]
 
@@ -387,13 +388,15 @@ class ExperimentEngine:
         # each lane gathers from its row by ``data_idx`` — not one per grid
         # cell, and never as a per-lane materialized copy (round_step
         # indexes the stacked rows lazily at each use site).
-        states = self._init_states(states, scns)
-        datas = self._materialize(datas)
+        with stage("init"):
+            states = self._init_states(states, scns)
+            datas = self._materialize(datas)
         step = self._round_step
 
         def one(state, scn, si, ai, di):
             if warm:
-                state = self._warmup(state, datas, di)
+                with stage("warmup"):
+                    state = self._warmup(state, datas, di)
 
             def body(s, xs):
                 do_eval, do_recluster = xs
@@ -440,42 +443,33 @@ class ExperimentEngine:
         runs = list(itertools.product(strategies, aggregators, seeds, scenarios))
         states, scn_list, sidx, aidx = [], [], [], []
         data_rows, data_row_of, didx = [], {}, []
-        for strategy, aggregator, seed, scenario in runs:
-            tc = self._traffic_of(scenario)
-            if self.init_on_device:
-                # pure key stacking: model init + twin seeding + client
-                # partitioning all happen inside the compiled grid program
-                self._ensure_spec()
-                st = experiment_key(self.dataset, strategy, seed)
-                scn = scenario_params(tc)
-                si = self.strategies.index(strategy)
-                da = (st, scn)
-            else:
-                st, da, scn, si = self.init_run(strategy, seed, scenario)
-            states.append(st)
-            scn_list.append(scn)
-            sidx.append(si)
-            aidx.append(self.aggregators.index(aggregator))
-            # client shards/test set depend on (strategy, seed) plus the
-            # spawn-layout signature (platoon regroups regions) — NEVER the
-            # aggregator (a server-side rule over the same data streams);
-            # keep one stacked row per unique triple (see _grid)
-            pair = (strategy, seed, data_signature(tc))
-            if pair not in data_row_of:
-                data_row_of[pair] = len(data_rows)
-                data_rows.append(da)
-            didx.append(data_row_of[pair])
+        with span("lanes", lanes=len(runs)):
+            for strategy, aggregator, seed, scenario in runs:
+                tc = self._traffic_of(scenario)
+                if self.init_on_device:
+                    # pure key stacking: model init + twin seeding + client
+                    # partitioning all happen inside the compiled grid program
+                    self._ensure_spec()
+                    st = experiment_key(self.dataset, strategy, seed)
+                    scn = scenario_params(tc)
+                    si = self.strategies.index(strategy)
+                    da = (st, scn)
+                else:
+                    st, da, scn, si = self.init_run(strategy, seed, scenario)
+                states.append(st)
+                scn_list.append(scn)
+                sidx.append(si)
+                aidx.append(self.aggregators.index(aggregator))
+                # client shards/test set depend on (strategy, seed) plus the
+                # spawn-layout signature (platoon regroups regions) — NEVER the
+                # aggregator (a server-side rule over the same data streams);
+                # keep one stacked row per unique triple (see _grid)
+                pair = (strategy, seed, data_signature(tc))
+                if pair not in data_row_of:
+                    data_row_of[pair] = len(data_rows)
+                    data_rows.append(da)
+                didx.append(data_row_of[pair])
         stack = lambda *xs: jnp.stack(xs)
-        if self.init_on_device:
-            states = jnp.stack(states)
-        else:
-            states = jax.tree_util.tree_map(stack, *states)
-        scns = stack_scenarios(scn_list)
-        strat_idx = jnp.asarray(sidx, jnp.int32)
-        agg_idx = jnp.asarray(aidx, jnp.int32)
-        data_idx = np.asarray(didx, np.int32)
-        flags = (_eval_flags(rounds, eval_every),
-                 _recluster_flags(rounds, self.fl.recluster_every))
 
         def stack_rows(rows, order=None):
             """Stack dedup data rows (optionally gathered in ``order``)."""
@@ -490,22 +484,37 @@ class ExperimentEngine:
         G = len(runs)
         nsh = self.grid_shards()
         self.last_data_plan = None
-        if nsh > 1:
-            # pad grid rows to the shard count (repeating the last row),
-            # shard the leading axis, slice the metrics back afterwards
-            pad = (-G) % nsh
-            if pad:
-                pad_idx = np.concatenate([np.arange(G), np.full(pad, G - 1)])
-                take = lambda x: x[pad_idx]
-                states = jax.tree_util.tree_map(take, states)
-                scns = jax.tree_util.tree_map(take, scns)
-                strat_idx, agg_idx = strat_idx[pad_idx], agg_idx[pad_idx]
-                data_idx = data_idx[pad_idx]
-            spec = resolve_pspec(("grid",), (G + pad,), self.mesh, TRAIN_RULES)
-            if len(spec) and spec[0] is not None:
+        sharded = False
+        with span("stack"):
+            if self.init_on_device:
+                states = jnp.stack(states)
+            else:
+                states = jax.tree_util.tree_map(stack, *states)
+            scns = stack_scenarios(scn_list)
+            strat_idx = jnp.asarray(sidx, jnp.int32)
+            agg_idx = jnp.asarray(aidx, jnp.int32)
+            data_idx = np.asarray(didx, np.int32)
+            flags = (_eval_flags(rounds, eval_every),
+                     _recluster_flags(rounds, self.fl.recluster_every))
+            if nsh > 1:
+                # pad grid rows to the shard count (repeating the last row),
+                # shard the leading axis, slice the metrics back afterwards
+                pad = (-G) % nsh
+                if pad:
+                    pad_idx = np.concatenate([np.arange(G), np.full(pad, G - 1)])
+                    take = lambda x: x[pad_idx]
+                    states = jax.tree_util.tree_map(take, states)
+                    scns = jax.tree_util.tree_map(take, scns)
+                    strat_idx, agg_idx = strat_idx[pad_idx], agg_idx[pad_idx]
+                    data_idx = data_idx[pad_idx]
+                spec = resolve_pspec(("grid",), (G + pad,), self.mesh, TRAIN_RULES)
+                # a spec that does not split the grid (should not happen
+                # after padding) falls back to the vmapped program
+                sharded = len(spec) > 0 and spec[0] is not None
+            if sharded:
                 # shard-local RoundData: ship each device only the dedup
                 # rows its lanes gather, remap data_idx to local positions
-                shard_rows, local_idx = shard_local_rows(data_idx, nsh)
+                shard_rows, data_idx = shard_local_rows(data_idx, nsh)
                 M = shard_rows.shape[1]
                 datas = stack_rows(data_rows, order=shard_rows.reshape(-1))
                 self.last_data_plan = {
@@ -520,22 +529,21 @@ class ExperimentEngine:
                     self._sharded_fn = self._build_sharded(
                         PartitionSpec(spec[0]), PartitionSpec(dspec[0])
                     )
+            else:
+                datas = stack_rows(data_rows)
+            data_idx = jnp.asarray(data_idx)
+        with span("launch"):
+            if sharded:
                 _, metrics = self._sharded_fn(
-                    states, datas, scns, strat_idx, agg_idx,
-                    jnp.asarray(local_idx), flags,
+                    states, datas, scns, strat_idx, agg_idx, data_idx, flags,
                 )
-                metrics = jax.tree_util.tree_map(lambda x: x[:G], metrics)
-            else:  # divisibility fallback (should not happen after padding)
+            else:
                 _, metrics = self._grid_fn(
-                    states, stack_rows(data_rows), scns, strat_idx, agg_idx,
-                    jnp.asarray(data_idx), flags, warm=self.warmup_enabled,
+                    states, datas, scns, strat_idx, agg_idx, data_idx, flags,
+                    warm=self.warmup_enabled,
                 )
+            if nsh > 1:
                 metrics = jax.tree_util.tree_map(lambda x: x[:G], metrics)
-        else:
-            _, metrics = self._grid_fn(
-                states, stack_rows(data_rows), scns, strat_idx, agg_idx,
-                jnp.asarray(data_idx), flags, warm=self.warmup_enabled,
-            )
         scenarios = list(scenarios)
 
         def _label(sc):

@@ -119,6 +119,7 @@ from repro.kernels.ops import (
 )
 from repro.sharding import split_params
 from repro.utils import flatten_to_vector, fold_in_str, unflatten_from_vector
+from repro.utils.tracing import stage
 
 # lax.switch branch order: the traced strategy axis indexes this tuple.
 STRATEGY_ORDER: Tuple[str, ...] = ("greedy", "gossip", "data", "network", "contextual")
@@ -635,363 +636,375 @@ def make_round_step(loss_fn, fl: FLConfig, cohort_size: int, model_bytes: float,
 
     def round_step(state: RoundState, scn, strategy_idx, aggregator_idx,
                    data: RoundData, do_eval, do_recluster=None, data_idx=None):
-        rk = jax.random.fold_in(state.key, state.round)
+        # each stage's ops carry an ``fl.<stage>`` scope in their op_name
+        # (repro.utils.tracing), which names their device time in a trace
+        with stage("geometry"):
+            # ---- stages 1+2: fuse CAM/CPM, predict, price the topology -
+            rk = jax.random.fold_in(state.key, state.round)
+            lat_pred, connected = _predicted(state.twin, scn, rk)
 
-        # ---- stages 1+2: fuse CAM/CPM, predict, price the topology -----
-        lat_pred, connected = _predicted(state.twin, scn, rk)
+        with stage("select"):
+            # ---- stage 4: elect --------------------------------------------
+            mask = _elect(connected, lat_pred, state.clusters, rk, strategy_idx)
+            n_selected = jnp.sum(mask).astype(jnp.int32)
 
-        # ---- stage 4: elect --------------------------------------------
-        mask = _elect(connected, lat_pred, state.clusters, rk, strategy_idx)
-        n_selected = jnp.sum(mask).astype(jnp.int32)
+        with stage("train"):
+            # ---- fixed-size cohort gather ----------------------------------
+            # Selected client ids in ascending order fill the first slots; the
+            # rest are no-op padding (zeroed data + zeroed updates) — never a
+            # redundant retraining of client 0.  Under a stacked ``data`` the
+            # row and cohort gathers fuse into ONE (data_idx, idx_c) gather per
+            # leaf — no per-lane copy of the full client shard.
+            order = jnp.where(mask, jnp.arange(N), N + jnp.arange(N))
+            idx = jnp.sort(order)[:K]
+            slot_valid = idx < N
+            idx_c = jnp.where(slot_valid, idx, 0)
 
-        # ---- fixed-size cohort gather ----------------------------------
-        # Selected client ids in ascending order fill the first slots; the
-        # rest are no-op padding (zeroed data + zeroed updates) — never a
-        # redundant retraining of client 0.  Under a stacked ``data`` the
-        # row and cohort gathers fuse into ONE (data_idx, idx_c) gather per
-        # leaf — no per-lane copy of the full client shard.
-        order = jnp.where(mask, jnp.arange(N), N + jnp.arange(N))
-        idx = jnp.sort(order)[:K]
-        slot_valid = idx < N
-        idx_c = jnp.where(slot_valid, idx, 0)
-
-        # ---- realized round economics on the TRUE evolved topology -----
-        # Computed BEFORE training: a pure dataflow reorder (every PRNG
-        # stream is name-folded and nothing here reads the updates), so
-        # flat lanes trace the same values bitwise — and the blocked lane
-        # must know the per-client weights before its chunk scan trains
-        # anything.
-        compute_i = compute_s * state.twin.compute_factor[idx_c]
-        nsel_f = jnp.maximum(n_selected.astype(jnp.float32), 1.0)
-        mean_compute = jnp.sum(jnp.where(slot_valid, compute_i, 0.0)) / nsel_f
-        mid_twin = advance_twin(
-            state.twin, scn, fold_in_str(rk, "mid"), mean_compute,
-            num_substeps=ADVANCE_SUBSTEPS,
-        )
-        if hierarchical:
-            real_lat, still_conn, rid = _realized(mid_twin, scn, rk)
-        else:
-            real_lat, still_conn = _realized(mid_twin, scn, rk)
-        ok = slot_valid & still_conn[idx_c]
-        ok_any = jnp.any(ok)
-        timeout = jnp.float32(fl.round_timeout_s)
-        per_slot = real_lat[idx_c] + compute_i
-        # a selected client that missed the deadline costs the full timeout;
-        # padding slots must not contribute to the round maximum
-        slot_pay = jnp.where(ok, per_slot, timeout)
-        dur_core = jnp.max(jnp.where(slot_valid, slot_pay, -jnp.inf))
-        duration = jnp.where(
-            n_selected > 0, dur_core + fl.server_agg_s, timeout
-        )
-
-        # ---- FedAvg weights (flat, or RSU-routed two-tier) -------------
-        # weights come from the per-client sample counts the data row
-        # carries (equal to fl.samples_per_client while every slot fills)
-        counts_k = _row(data.counts, data_idx)[idx_c]
-        if hierarchical:
-            R = n_rsu_of(scn)
-            live = rsu_up_mask(scn)
-            rid_k = rid[idx_c]
-            # the attachment argmin never picks a dark RSU, so this fold is
-            # the identity whenever attachments are current — it is the
-            # contract that a dark RSU's partial NEVER reaches the server
-            live_k = live[rid_k]
-
-            def _w_strict(m, c):
-                return rsu_normalized_weights(m & live_k, c, rid_k, live, R)[0]
-
-            def _w_stale(m, c):
-                # float-valued discounted counts don't reassociate exactly:
-                # keep the flat-sum normalizer (mass_norm=False) so the
-                # stale lane stays bitwise with its flat sibling too
-                return rsu_normalized_weights(
-                    m & live_k, c, rid_k, live, R, mass_norm=False
-                )[0]
-        else:
-            _w_strict = _w_stale = normalized_weights
-
-        if plain_fedavg:
-            # THE pre-registry path: plain FedAvg weights, server moment
-            # vectors ride the carry untouched
-            w = _w_strict(ok, counts_k)
-            upd_any = ok_any
-        else:
-            gidx = agg_global[aggregator_idx]
-            is_stale = gidx == STALE_IDX
-            # stale rule: deadline-missing stragglers keep a discounted
-            # weight from their REALIZED round time instead of dropping to
-            # zero; survivors and every other rule keep the strict weights
-            # bitwise (jnp.where passes the untaken side through untouched)
-            w_strict = _w_strict(ok, counts_k)
-            disc = jnp.where(ok, 1.0, staleness_scale(per_slot, timeout))
-            w_stale = _w_stale(slot_valid, counts_k * disc)
-            w = jnp.where(is_stale, w_stale, w_strict)
-            # under stale ANY selected client contributes an update; round
-            # economics (duration, base twin, metrics) keep the strict
-            # deadline semantics so aggregator lanes stay comparable (see
-            # the module docstring for how far that identity extends)
-            upd_any = jnp.where(is_stale, n_selected > 0, ok_any)
-
-        # ---- fedbuff: drain arrived buffer slots, place new stragglers -
-        # All mask-based on the fixed (Kb,) slot axis: which occupied slots
-        # have ARRIVED by round end drains into the server step (discounted
-        # by realized cross-round lateness, gated on the fill threshold);
-        # this round's deadline-missers compact into the freed slots.
-        if has_fedbuff:
-            is_fedbuff = gidx == FEDBUFF_IDX
-            end_time = state.sim_time + duration
-            arrived = state.buf_mask & (state.buf_arrive <= end_time)
-            n_arrived = jnp.sum(arrived).astype(jnp.int32)
-            drain_fire = is_fedbuff & (n_arrived >= buffer_fill)
-            disc_b = staleness_scale(
-                jnp.maximum(end_time - state.buf_sent, 0.0), timeout
+        with stage("geometry"):
+            # ---- realized round economics on the TRUE evolved topology -----
+            # Computed BEFORE training: a pure dataflow reorder (every PRNG
+            # stream is name-folded and nothing here reads the updates), so
+            # flat lanes trace the same values bitwise — and the blocked lane
+            # must know the per-client weights before its chunk scan trains
+            # anything.
+            compute_i = compute_s * state.twin.compute_factor[idx_c]
+            nsel_f = jnp.maximum(n_selected.astype(jnp.float32), 1.0)
+            mean_compute = jnp.sum(jnp.where(slot_valid, compute_i, 0.0)) / nsel_f
+            mid_twin = advance_twin(
+                state.twin, scn, fold_in_str(rk, "mid"), mean_compute,
+                num_substeps=ADVANCE_SUBSTEPS,
             )
-            # normalize by the UNDISCOUNTED drained mass (the same 1e-9
-            # guard as normalized_weights) so the staleness discount
-            # genuinely shrinks the step instead of cancelling out
-            mass_b = jnp.sum(jnp.where(arrived, state.buf_weight, 0.0))
-            bw = jnp.where(
-                drain_fire & arrived,
-                state.buf_weight * disc_b / jnp.maximum(mass_b, 1e-9),
-                0.0,
+            if hierarchical:
+                real_lat, still_conn, rid = _realized(mid_twin, scn, rk)
+            else:
+                real_lat, still_conn = _realized(mid_twin, scn, rk)
+            ok = slot_valid & still_conn[idx_c]
+            ok_any = jnp.any(ok)
+            timeout = jnp.float32(fl.round_timeout_s)
+            per_slot = real_lat[idx_c] + compute_i
+            # a selected client that missed the deadline costs the full timeout;
+            # padding slots must not contribute to the round maximum
+            slot_pay = jnp.where(ok, per_slot, timeout)
+            dur_core = jnp.max(jnp.where(slot_valid, slot_pay, -jnp.inf))
+            duration = jnp.where(
+                n_selected > 0, dur_core + fl.server_agg_s, timeout
             )
-            keep = state.buf_mask & ~(drain_fire & arrived)
-            # free-slot compaction: the i-th straggler takes the i-th free
-            # slot; ranks beyond the free capacity gather values >= Kb and
-            # the scatters below drop them (newest-overflow-dropped policy)
-            strag = slot_valid & ~ok & is_fedbuff
-            free_order = jnp.sort(
-                jnp.where(keep, Kb + jnp.arange(Kb), jnp.arange(Kb))
-            )
-            rank = jnp.cumsum(strag) - 1
-            slot = jnp.where(
-                strag & (rank < Kb),
-                free_order[jnp.clip(rank, 0, Kb - 1)],
-                2 * Kb,
-            )
-            n_buffered = jnp.sum(strag & (slot < Kb)).astype(jnp.int32)
-            n_drained = jnp.where(drain_fire, n_arrived, 0).astype(jnp.int32)
-            # a drain with zero in-round survivors is still a server step
-            upd_any = jnp.where(is_fedbuff, ok_any | drain_fire, upd_any)
-        else:
-            n_buffered = jnp.zeros((), jnp.int32)
-            n_drained = jnp.zeros((), jnp.int32)
 
-        # ---- local training + edge reduce ------------------------------
-        params = unflatten_from_vector(state.params, param_spec)
-        if client_block:
-            # chunk-streamed two-tier lane: an inner scan trains fixed-size
-            # client chunks and segment-reduces each straight into (R, P)
-            # per-RSU partials riding the chunk carry — the full (K, P)
-            # update matrix never materializes.  Per-client PRNG keys come
-            # from ONE cohort-wide split (the exact stream the unblocked
-            # trainer consumes), sliced per chunk; padding slots repeat
-            # key 0 and train zeroed data into zero-masked updates.
-            B = client_block
-            nC = -(-K // B)
-            pad = nC * B - K
+        with stage("server"):
+            # ---- FedAvg weights (flat, or RSU-routed two-tier) -------------
+            # weights come from the per-client sample counts the data row
+            # carries (equal to fl.samples_per_client while every slot fills)
+            counts_k = _row(data.counts, data_idx)[idx_c]
+            if hierarchical:
+                R = n_rsu_of(scn)
+                live = rsu_up_mask(scn)
+                rid_k = rid[idx_c]
+                # the attachment argmin never picks a dark RSU, so this fold is
+                # the identity whenever attachments are current — it is the
+                # contract that a dark RSU's partial NEVER reaches the server
+                live_k = live[rid_k]
 
-            def _pad_k(x, fill):
-                if pad == 0:
-                    return x
-                return jnp.concatenate(
-                    [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)]
-                )
+                def _w_strict(m, c):
+                    return rsu_normalized_weights(m & live_k, c, rid_k, live, R)[0]
 
-            keys_all = jax.random.split(fold_in_str(rk, "local"), K)
-            if pad:
-                kd = jax.random.key_data(keys_all)
-                kd = jnp.concatenate([kd, jnp.tile(kd[:1], (pad, 1))])
-                keys_all = jax.random.wrap_key_data(kd)
-            xs = (
-                _pad_k(idx_c, 0).reshape(nC, B),
-                _pad_k(slot_valid, False).reshape(nC, B),
-                _pad_k(w, 0.0).reshape(nC, B),
-                _pad_k(rid_k, 0).reshape(nC, B),
-                _pad_k(ok, False).reshape(nC, B),
-                keys_all.reshape(nC, B),
-            )
+                def _w_stale(m, c):
+                    # float-valued discounted counts don't reassociate exactly:
+                    # keep the flat-sum normalizer (mass_norm=False) so the
+                    # stale lane stays bitwise with its flat sibling too
+                    return rsu_normalized_weights(
+                        m & live_k, c, rid_k, live, R, mass_norm=False
+                    )[0]
+            else:
+                _w_strict = _w_stale = normalized_weights
+
+            if plain_fedavg:
+                # THE pre-registry path: plain FedAvg weights, server moment
+                # vectors ride the carry untouched
+                w = _w_strict(ok, counts_k)
+                upd_any = ok_any
+            else:
+                gidx = agg_global[aggregator_idx]
+                is_stale = gidx == STALE_IDX
+                # stale rule: deadline-missing stragglers keep a discounted
+                # weight from their REALIZED round time instead of dropping to
+                # zero; survivors and every other rule keep the strict weights
+                # bitwise (jnp.where passes the untaken side through untouched)
+                w_strict = _w_strict(ok, counts_k)
+                disc = jnp.where(ok, 1.0, staleness_scale(per_slot, timeout))
+                w_stale = _w_stale(slot_valid, counts_k * disc)
+                w = jnp.where(is_stale, w_stale, w_strict)
+                # under stale ANY selected client contributes an update; round
+                # economics (duration, base twin, metrics) keep the strict
+                # deadline semantics so aggregator lanes stay comparable (see
+                # the module docstring for how far that identity extends)
+                upd_any = jnp.where(is_stale, n_selected > 0, ok_any)
+
+            # ---- fedbuff: drain arrived buffer slots, place new stragglers -
+            # All mask-based on the fixed (Kb,) slot axis: which occupied slots
+            # have ARRIVED by round end drains into the server step (discounted
+            # by realized cross-round lateness, gated on the fill threshold);
+            # this round's deadline-missers compact into the freed slots.
             if has_fedbuff:
-                # ring-buffer slot per cohort position (>= Kb drops);
-                # padding chunks scatter nowhere
-                xs = xs + (_pad_k(slot, 2 * Kb).reshape(nC, B),)
+                is_fedbuff = gidx == FEDBUFF_IDX
+                end_time = state.sim_time + duration
+                arrived = state.buf_mask & (state.buf_arrive <= end_time)
+                n_arrived = jnp.sum(arrived).astype(jnp.int32)
+                drain_fire = is_fedbuff & (n_arrived >= buffer_fill)
+                disc_b = staleness_scale(
+                    jnp.maximum(end_time - state.buf_sent, 0.0), timeout
+                )
+                # normalize by the UNDISCOUNTED drained mass (the same 1e-9
+                # guard as normalized_weights) so the staleness discount
+                # genuinely shrinks the step instead of cancelling out
+                mass_b = jnp.sum(jnp.where(arrived, state.buf_weight, 0.0))
+                bw = jnp.where(
+                    drain_fire & arrived,
+                    state.buf_weight * disc_b / jnp.maximum(mass_b, 1e-9),
+                    0.0,
+                )
+                keep = state.buf_mask & ~(drain_fire & arrived)
+                # free-slot compaction: the i-th straggler takes the i-th free
+                # slot; ranks beyond the free capacity gather values >= Kb and
+                # the scatters below drop them (newest-overflow-dropped policy)
+                strag = slot_valid & ~ok & is_fedbuff
+                free_order = jnp.sort(
+                    jnp.where(keep, Kb + jnp.arange(Kb), jnp.arange(Kb))
+                )
+                rank = jnp.cumsum(strag) - 1
+                slot = jnp.where(
+                    strag & (rank < Kb),
+                    free_order[jnp.clip(rank, 0, Kb - 1)],
+                    2 * Kb,
+                )
+                n_buffered = jnp.sum(strag & (slot < Kb)).astype(jnp.int32)
+                n_drained = jnp.where(drain_fire, n_arrived, 0).astype(jnp.int32)
+                # a drain with zero in-round survivors is still a server step
+                upd_any = jnp.where(is_fedbuff, ok_any | drain_fire, upd_any)
+            else:
+                n_buffered = jnp.zeros((), jnp.int32)
+                n_drained = jnp.zeros((), jnp.int32)
 
-            def _chunk(carry, xs_c):
+        with stage("train"):
+            # ---- local training + edge reduce ------------------------------
+            params = unflatten_from_vector(state.params, param_spec)
+            if client_block:
+                # chunk-streamed two-tier lane: an inner scan trains fixed-size
+                # client chunks and segment-reduces each straight into (R, P)
+                # per-RSU partials riding the chunk carry — the full (K, P)
+                # update matrix never materializes.  Per-client PRNG keys come
+                # from ONE cohort-wide split (the exact stream the unblocked
+                # trainer consumes), sliced per chunk; padding slots repeat
+                # key 0 and train zeroed data into zero-masked updates.
+                B = client_block
+                nC = -(-K // B)
+                pad = nC * B - K
+
+                def _pad_k(x, fill):
+                    if pad == 0:
+                        return x
+                    return jnp.concatenate(
+                        [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)]
+                    )
+
+                keys_all = jax.random.split(fold_in_str(rk, "local"), K)
+                if pad:
+                    kd = jax.random.key_data(keys_all)
+                    kd = jnp.concatenate([kd, jnp.tile(kd[:1], (pad, 1))])
+                    keys_all = jax.random.wrap_key_data(kd)
+                xs = (
+                    _pad_k(idx_c, 0).reshape(nC, B),
+                    _pad_k(slot_valid, False).reshape(nC, B),
+                    _pad_k(w, 0.0).reshape(nC, B),
+                    _pad_k(rid_k, 0).reshape(nC, B),
+                    _pad_k(ok, False).reshape(nC, B),
+                    keys_all.reshape(nC, B),
+                )
                 if has_fedbuff:
-                    partials, sketches, sketch_age, buf = carry
-                    i_c, v_c, w_c, r_c, ok_c, k_c, s_c = xs_c
+                    # ring-buffer slot per cohort position (>= Kb drops);
+                    # padding chunks scatter nowhere
+                    xs = xs + (_pad_k(slot, 2 * Kb).reshape(nC, B),)
+
+                def _chunk(carry, xs_c):
+                    if has_fedbuff:
+                        partials, sketches, sketch_age, buf = carry
+                        i_c, v_c, w_c, r_c, ok_c, k_c, s_c = xs_c
+                    else:
+                        partials, sketches, sketch_age = carry
+                        i_c, v_c, w_c, r_c, ok_c, k_c = xs_c
+                    if data_idx is None:
+                        imgs_c = data.images[i_c]
+                        lbls_c = data.labels[i_c]
+                    else:
+                        imgs_c = data.images[data_idx, i_c]
+                        lbls_c = data.labels[data_idx, i_c]
+                    dm = v_c.reshape((B,) + (1,) * (imgs_c.ndim - 1))
+                    imgs_c = imgs_c * dm
+                    lbls_c = jnp.where(v_c[:, None], lbls_c, 0)
+                    _, vb = trainer(params, imgs_c, lbls_c, k_c)
+                    vb = vb * v_c[:, None]
+                    if half:
+                        # the comm lane: chunk deltas travel (and park in the
+                        # fedbuff ring) at the compute dtype
+                        vb = vb.astype(cd)
+                    part_c, _ = rsu_reduce_auto(
+                        vb, w_c, r_c, R, out_dtype=cd if half else None
+                    )
+                    sks_c = jax.vmap(
+                        lambda v: apply_sketch(v, state.sketch_sign, fl.sketch_dim)
+                    )(vb)
+                    scat = jnp.where(ok_c, i_c, N)  # out-of-bounds rows drop
+                    sketches = sketches.at[scat].set(sks_c, mode="drop")
+                    sketch_age = sketch_age.at[scat].set(0.0, mode="drop")
+                    if has_fedbuff:
+                        # straggler updates park in the ring buffer (vb is
+                        # already zero-masked on padding slots)
+                        buf = buf.at[s_c].set(vb, mode="drop")
+                        return (partials + part_c, sketches, sketch_age, buf), None
+                    return (partials + part_c, sketches, sketch_age), None
+
+                # the (R, P) per-RSU partials ride the chunk carry at the
+                # compute dtype (fp32 default; bf16 halves the carry)
+                carry0 = (jnp.zeros((R, P), cd), state.sketches,
+                          state.sketch_age)
+                if has_fedbuff:
+                    carry0 = carry0 + (
+                        jnp.where(keep[:, None], state.buf_delta, 0.0),
+                    )
+                    (partials, sketches, sketch_age, buf_delta), _ = jax.lax.scan(
+                        _chunk, carry0, xs
+                    )
                 else:
-                    partials, sketches, sketch_age = carry
-                    i_c, v_c, w_c, r_c, ok_c, k_c = xs_c
+                    (partials, sketches, sketch_age), _ = jax.lax.scan(
+                        _chunk, carry0, xs
+                    )
+                sketch_age = sketch_age + 1.0
+                # server tier: R live partials (weights already folded in at
+                # the edge) reduce through the same fused flat pass
+                red, red_w, bp = partials, live.astype(jnp.float32), \
+                    pick_block_p(R + buf_rows, P, itemsize=itemsize)
+            else:
                 if data_idx is None:
-                    imgs_c = data.images[i_c]
-                    lbls_c = data.labels[i_c]
+                    imgs, lbls = data.images[idx_c], data.labels[idx_c]
                 else:
-                    imgs_c = data.images[data_idx, i_c]
-                    lbls_c = data.labels[data_idx, i_c]
-                dm = v_c.reshape((B,) + (1,) * (imgs_c.ndim - 1))
-                imgs_c = imgs_c * dm
-                lbls_c = jnp.where(v_c[:, None], lbls_c, 0)
-                _, vb = trainer(params, imgs_c, lbls_c, k_c)
-                vb = vb * v_c[:, None]
+                    imgs = data.images[data_idx, idx_c]
+                    lbls = data.labels[data_idx, idx_c]
+                dmask = slot_valid.reshape((K,) + (1,) * (imgs.ndim - 1))
+                imgs = imgs * dmask
+                lbls = jnp.where(slot_valid[:, None], lbls, 0)
+                _, vecs = trainer(params, imgs, lbls, fold_in_str(rk, "local"))
+                vecs = vecs * slot_valid[:, None]
                 if half:
-                    # the comm lane: chunk deltas travel (and park in the
-                    # fedbuff ring) at the compute dtype
-                    vb = vb.astype(cd)
-                part_c, _ = rsu_reduce_auto(
-                    vb, w_c, r_c, R, out_dtype=cd if half else None
-                )
-                sks_c = jax.vmap(
+                    # the comm lane: update vectors travel to the reduce (and
+                    # park in the fedbuff ring) at the compute dtype
+                    vecs = vecs.astype(cd)
+
+                # ---- deadline rule: survivors report sketches --------------
+                sks = jax.vmap(
                     lambda v: apply_sketch(v, state.sketch_sign, fl.sketch_dim)
-                )(vb)
-                scat = jnp.where(ok_c, i_c, N)  # out-of-bounds rows drop
-                sketches = sketches.at[scat].set(sks_c, mode="drop")
-                sketch_age = sketch_age.at[scat].set(0.0, mode="drop")
+                )(vecs)
+                scatter = jnp.where(ok, idx_c, N)  # out-of-bounds rows drop
+                sketches = state.sketches.at[scatter].set(sks, mode="drop")
+                sketch_age = state.sketch_age.at[scatter].set(0.0, mode="drop") + 1.0
                 if has_fedbuff:
-                    # straggler updates park in the ring buffer (vb is
-                    # already zero-masked on padding slots)
-                    buf = buf.at[s_c].set(vb, mode="drop")
-                    return (partials + part_c, sketches, sketch_age, buf), None
-                return (partials + part_c, sketches, sketch_age), None
+                    # straggler updates park in the ring buffer: drained slots
+                    # zero out, this round's deadline-missers scatter into the
+                    # freed slots (slot >= Kb rows drop)
+                    buf_delta = jnp.where(
+                        keep[:, None], state.buf_delta, 0.0
+                    ).at[slot].set(vecs, mode="drop")
+                red, red_w, bp = vecs, w, pick_block_p(K + buf_rows, P,
+                                                       itemsize=itemsize)
 
-            # the (R, P) per-RSU partials ride the chunk carry at the
-            # compute dtype (fp32 default; bf16 halves the carry)
-            carry0 = (jnp.zeros((R, P), cd), state.sketches,
-                      state.sketch_age)
-            if has_fedbuff:
-                carry0 = carry0 + (
-                    jnp.where(keep[:, None], state.buf_delta, 0.0),
+        with stage("server"):
+            # ---- server update over deadline survivors (one fused flat pass)
+            if plain_fedavg:
+                delta = fedavg_reduce_auto(red, red_w, block_p=bp)
+                params_vec = jnp.where(
+                    upd_any, apply_delta_flat(state.params, delta), state.params
                 )
-                (partials, sketches, sketch_age, buf_delta), _ = jax.lax.scan(
-                    _chunk, carry0, xs
+                opt_m, opt_v = state.opt_m, state.opt_v
+            elif has_fedbuff:
+                # every lane of a fedbuff-bearing registry routes through the
+                # buffered kernel: drain=False passes the unbuffered delta
+                # through bitwise, so non-fedbuff lanes are unchanged.  The
+                # PRE-scatter buffer is reduced — bw is nonzero only on slots
+                # drained this round.
+                new_p, new_m, new_v = server_update_buffered_auto(
+                    red, red_w, state.buf_delta, bw, state.params, state.opt_m,
+                    state.opt_v, gidx, state.round, drain_fire, eta=hp.eta,
+                    beta1=hp.beta1, beta2=hp.beta2, tau=hp.tau, block_p=bp,
                 )
+                params_vec = jnp.where(upd_any, new_p, state.params)
+                opt_m = jnp.where(upd_any, new_m, state.opt_m)
+                opt_v = jnp.where(upd_any, new_v, state.opt_v)
             else:
-                (partials, sketches, sketch_age), _ = jax.lax.scan(
-                    _chunk, carry0, xs
+                new_p, new_m, new_v = server_update_auto(
+                    red, red_w, state.params, state.opt_m, state.opt_v, gidx,
+                    state.round, eta=hp.eta, beta1=hp.beta1, beta2=hp.beta2,
+                    tau=hp.tau, block_p=bp,
                 )
-            sketch_age = sketch_age + 1.0
-            # server tier: R live partials (weights already folded in at
-            # the edge) reduce through the same fused flat pass
-            red, red_w, bp = partials, live.astype(jnp.float32), \
-                pick_block_p(R + buf_rows, P, itemsize=itemsize)
-        else:
-            if data_idx is None:
-                imgs, lbls = data.images[idx_c], data.labels[idx_c]
-            else:
-                imgs = data.images[data_idx, idx_c]
-                lbls = data.labels[data_idx, idx_c]
-            dmask = slot_valid.reshape((K,) + (1,) * (imgs.ndim - 1))
-            imgs = imgs * dmask
-            lbls = jnp.where(slot_valid[:, None], lbls, 0)
-            _, vecs = trainer(params, imgs, lbls, fold_in_str(rk, "local"))
-            vecs = vecs * slot_valid[:, None]
-            if half:
-                # the comm lane: update vectors travel to the reduce (and
-                # park in the fedbuff ring) at the compute dtype
-                vecs = vecs.astype(cd)
+                params_vec = jnp.where(upd_any, new_p, state.params)
+                opt_m = jnp.where(upd_any, new_m, state.opt_m)
+                opt_v = jnp.where(upd_any, new_v, state.opt_v)
 
-            # ---- deadline rule: survivors report sketches --------------
-            sks = jax.vmap(
-                lambda v: apply_sketch(v, state.sketch_sign, fl.sketch_dim)
-            )(vecs)
-            scatter = jnp.where(ok, idx_c, N)  # out-of-bounds rows drop
-            sketches = state.sketches.at[scatter].set(sks, mode="drop")
-            sketch_age = state.sketch_age.at[scatter].set(0.0, mode="drop") + 1.0
+            # ---- fedbuff: ring-buffer metadata follows the delta scatter ---
             if has_fedbuff:
-                # straggler updates park in the ring buffer: drained slots
-                # zero out, this round's deadline-missers scatter into the
-                # freed slots (slot >= Kb rows drop)
-                buf_delta = jnp.where(
-                    keep[:, None], state.buf_delta, 0.0
-                ).at[slot].set(vecs, mode="drop")
-            red, red_w, bp = vecs, w, pick_block_p(K + buf_rows, P,
-                                                   itemsize=itemsize)
+                # a parked straggler's update is modeled as landing one full
+                # deadline later (or its realized round time, if even slower)
+                arrive_k = state.sim_time + jnp.maximum(per_slot, timeout)
+                buf_arrive = jnp.where(
+                    keep, state.buf_arrive, 0.0
+                ).at[slot].set(arrive_k, mode="drop")
+                buf_sent = jnp.where(
+                    keep, state.buf_sent, 0.0
+                ).at[slot].set(jnp.broadcast_to(state.sim_time, (K,)), mode="drop")
+                buf_weight = jnp.where(
+                    keep, state.buf_weight, 0.0
+                ).at[slot].set(counts_k, mode="drop")
+                buf_mask = keep.at[slot].set(jnp.ones((K,), bool), mode="drop")
+            else:
+                buf_delta = state.buf_delta
+                buf_arrive = state.buf_arrive
+                buf_sent = state.buf_sent
+                buf_weight = state.buf_weight
+                buf_mask = state.buf_mask
 
-        # ---- server update over deadline survivors (one fused flat pass)
-        if plain_fedavg:
-            delta = fedavg_reduce_auto(red, red_w, block_p=bp)
-            params_vec = jnp.where(
-                upd_any, apply_delta_flat(state.params, delta), state.params
+        with stage("geometry"):
+            # ---- advance the twin to round end -----------------------------
+            base = jax.tree_util.tree_map(
+                lambda m, o: jnp.where(ok_any, m, o), mid_twin, state.twin
             )
-            opt_m, opt_v = state.opt_m, state.opt_v
-        elif has_fedbuff:
-            # every lane of a fedbuff-bearing registry routes through the
-            # buffered kernel: drain=False passes the unbuffered delta
-            # through bitwise, so non-fedbuff lanes are unchanged.  The
-            # PRE-scatter buffer is reduced — bw is nonzero only on slots
-            # drained this round.
-            new_p, new_m, new_v = server_update_buffered_auto(
-                red, red_w, state.buf_delta, bw, state.params, state.opt_m,
-                state.opt_v, gidx, state.round, drain_fire, eta=hp.eta,
-                beta1=hp.beta1, beta2=hp.beta2, tau=hp.tau, block_p=bp,
+            already = jnp.where(ok_any, mean_compute, 0.0)
+            rem = jnp.maximum(duration - already, 1e-3)
+            twin = advance_twin(
+                base, scn, fold_in_str(rk, "adv"), rem, num_substeps=ADVANCE_SUBSTEPS
             )
-            params_vec = jnp.where(upd_any, new_p, state.params)
-            opt_m = jnp.where(upd_any, new_m, state.opt_m)
-            opt_v = jnp.where(upd_any, new_v, state.opt_v)
-        else:
-            new_p, new_m, new_v = server_update_auto(
-                red, red_w, state.params, state.opt_m, state.opt_v, gidx,
-                state.round, eta=hp.eta, beta1=hp.beta1, beta2=hp.beta2,
-                tau=hp.tau, block_p=bp,
+
+        with stage("select"):
+            # ---- end of round: recluster on schedule ----------------------
+            # ``do_recluster`` arrives UNBATCHED from the engine's scan xs so
+            # the cond stays a genuine branch under vmap (a batched predicate
+            # would lower to a select that runs k-means EVERY round for every
+            # lane); the legacy host loop derives it from the (unbatched)
+            # round counter instead — same value, same branch.
+            new_round = state.round + 1
+            if do_recluster is None:
+                do_recluster = new_round % max(fl.recluster_every, 1) == 0
+            k_km = fold_in_str(jax.random.fold_in(state.key, new_round), "kmeans")
+            clusters = jax.lax.cond(
+                do_recluster,
+                lambda: kmeans_cluster(sketches, k_km, fl.num_clusters)[0],
+                lambda: state.clusters,
             )
-            params_vec = jnp.where(upd_any, new_p, state.params)
-            opt_m = jnp.where(upd_any, new_m, state.opt_m)
-            opt_v = jnp.where(upd_any, new_v, state.opt_v)
-
-        # ---- fedbuff: ring-buffer metadata follows the delta scatter ---
-        if has_fedbuff:
-            # a parked straggler's update is modeled as landing one full
-            # deadline later (or its realized round time, if even slower)
-            arrive_k = state.sim_time + jnp.maximum(per_slot, timeout)
-            buf_arrive = jnp.where(
-                keep, state.buf_arrive, 0.0
-            ).at[slot].set(arrive_k, mode="drop")
-            buf_sent = jnp.where(
-                keep, state.buf_sent, 0.0
-            ).at[slot].set(jnp.broadcast_to(state.sim_time, (K,)), mode="drop")
-            buf_weight = jnp.where(
-                keep, state.buf_weight, 0.0
-            ).at[slot].set(counts_k, mode="drop")
-            buf_mask = keep.at[slot].set(jnp.ones((K,), bool), mode="drop")
-        else:
-            buf_delta = state.buf_delta
-            buf_arrive = state.buf_arrive
-            buf_sent = state.buf_sent
-            buf_weight = state.buf_weight
-            buf_mask = state.buf_mask
-
-        # ---- advance the twin to round end -----------------------------
-        base = jax.tree_util.tree_map(
-            lambda m, o: jnp.where(ok_any, m, o), mid_twin, state.twin
-        )
-        already = jnp.where(ok_any, mean_compute, 0.0)
-        rem = jnp.maximum(duration - already, 1e-3)
-        twin = advance_twin(
-            base, scn, fold_in_str(rk, "adv"), rem, num_substeps=ADVANCE_SUBSTEPS
-        )
-
-        # ---- end of round: recluster on schedule, strided eval ---------
-        # ``do_recluster`` arrives UNBATCHED from the engine's scan xs so
-        # the cond stays a genuine branch under vmap (a batched predicate
-        # would lower to a select that runs k-means EVERY round for every
-        # lane); the legacy host loop derives it from the (unbatched)
-        # round counter instead — same value, same branch.
-        new_round = state.round + 1
-        if do_recluster is None:
-            do_recluster = new_round % max(fl.recluster_every, 1) == 0
-        k_km = fold_in_str(jax.random.fold_in(state.key, new_round), "kmeans")
-        clusters = jax.lax.cond(
-            do_recluster,
-            lambda: kmeans_cluster(sketches, k_km, fl.num_clusters)[0],
-            lambda: state.clusters,
-        )
         sim_time = state.sim_time + duration
-        test_acc, test_loss = jax.lax.cond(
-            do_eval,
-            lambda p: _eval(p, data, data_idx),
-            lambda p: (nan, nan),
-            params_vec,
-        )
+        with stage("eval"):
+            # ---- strided eval (the same unbatched-predicate cond) --------
+            test_acc, test_loss = jax.lax.cond(
+                do_eval,
+                lambda p: _eval(p, data, data_idx),
+                lambda p: (nan, nan),
+                params_vec,
+            )
 
         metrics = RoundMetrics(
             round=new_round,
