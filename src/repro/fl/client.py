@@ -27,8 +27,23 @@ def make_local_trainer(
 ) -> Callable:
     """Build jit'd cohort trainer.
 
-    Returned fn: (global_params, images (K,n,...), labels (K,n), key)
+    Returned fn: (global_params, images (..., n, D), labels (..., n), key,
+                  rows=None, valid=None)
       -> (updates pytree with leading K, update_vecs (K, P_flat))
+
+    ``images`` is a store of lane-dense sample rows, D = H*W*C features
+    minor (``partition.client_images``); ``labels`` matches it without the
+    feature axis.  ``rows`` is a tuple of leading-axis indices, each
+    broadcastable to (K,): cohort slot k trains on the n samples at
+    ``images[rows[0][k], rows[1][k], ...]`` — ``(idx_c,)`` for one store,
+    ``(data_idx, idx_c)`` for the engine's stacked dedup rows; the default
+    ``(arange(K),)`` trains a (K, n, D) store row by row.  Each SGD step
+    gathers its batch_size rows straight from the store by that index and
+    the step's permuted sample ids, so no per-slot copy of a client's
+    shard is ever made.  ``valid`` (K,) bool masks padding slots: their
+    gathered rows are multiplied by 0 and their labels set to 0.  The
+    model takes the (batch_size, D) rows and reshapes them to its image
+    shape.
 
     ``mu`` is the FedProx proximal coefficient: each local step descends
     ``loss + (mu/2) ||p - p_global||^2``, i.e. the traced gradient gains
@@ -54,8 +69,8 @@ def make_local_trainer(
             lambda w: w.astype(compute_dtype), tree
         )
 
-    def local_sgd(global_params, images, labels, key):
-        n = images.shape[0]
+    def local_sgd(global_params, images, labels, row, valid, key):
+        n = labels.shape[-1]
         spe = max(n // batch_size, 1)
         perm_keys = jax.random.split(key, epochs)
         idx = jax.vmap(lambda k: jax.random.permutation(k, n)[: spe * batch_size])(
@@ -64,7 +79,12 @@ def make_local_trainer(
         idx = idx.reshape(epochs * spe, batch_size)
 
         def step(p, bidx):
-            batch = {"images": images[bidx], "labels": labels[bidx]}
+            # one whole (D,) row per sample, gathered from the store itself
+            x, y = images[(*row, bidx)], labels[(*row, bidx)]
+            if valid is not None:
+                x = x * valid
+                y = jnp.where(valid, y, 0)
+            batch = {"images": x, "labels": y}
             if cast is None:
                 fwd = lambda pp: loss_fn(pp, batch)[0]
             else:
@@ -81,17 +101,20 @@ def make_local_trainer(
         return params
 
     @jax.jit
-    def train_cohort(global_params, images, labels, key):
-        K = images.shape[0]
+    def train_cohort(global_params, images, labels, key, rows=None, valid=None):
+        if rows is None:
+            rows = (jnp.arange(images.shape[0]),)
+        K = jnp.broadcast_shapes(*(jnp.shape(r) for r in rows))[0]
+        rows = tuple(jnp.broadcast_to(r, (K,)) for r in rows)
         # ``key`` is either one cohort key (split K ways here — the
         # historical behavior, bitwise-frozen) or an already-split (K,)
         # per-client key array: the chunk-streamed hierarchical lane splits
         # ONCE for the full cohort and slices per chunk, so each client
         # consumes the same key it would in the unblocked lane.
         keys = key if key.ndim == 1 else jax.random.split(key, K)
-        new_params = jax.vmap(lambda im, lb, k: local_sgd(global_params, im, lb, k))(
-            images, labels, keys
-        )
+        new_params = jax.vmap(
+            lambda r, v, k: local_sgd(global_params, images, labels, r, v, k)
+        )(rows, valid, keys)
         updates = jax.tree_util.tree_map(
             lambda new, old: new - old[None], new_params, global_params
         )

@@ -39,13 +39,13 @@ round.  This engine runs a whole grid as a single XLA program:
     step's flat layout comes from a ``jax.eval_shape`` trace);
   * client shards are partitioned ON DEVICE inside the compiled program
     (``partition_on_device=True``, the default): ``rounds.make_round_data``
-    materializes the (C, n, H, W, ch) shards per unique data row under
+    materializes the (C, n, D) sample-row shards per unique data row under
     jit, so grid size is bounded by device memory, not host RAM;
   * the stacked rows are NEVER copied per lane: ``round_step`` gathers
-    ``leaf[data_idx, ...]`` lazily at each use site (one fused gather for
-    the K-client cohort, a test-set gather only on eval rounds), so the
-    per-lane client-shard copies the old per-lane ``tree_map`` gather
-    materialized are gone;
+    ``leaf[data_idx, ...]`` lazily at each use site (each SGD step gathers
+    its batch as whole feature-minor rows by (data_idx, client, sample),
+    a test-set gather only on eval rounds), so neither a per-lane shard
+    copy nor a per-round cohort block is ever materialized;
   * per-round test evaluation is hoisted to every ``eval_every`` rounds
     (the final round always evaluates).
 

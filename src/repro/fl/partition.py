@@ -12,15 +12,16 @@ Shape conventions:
 
   * ``partition_labels``  -> (C, n) int32 — the *index map*: which shared
     prototype each of client c's n samples points at;
-  * ``client_images``     -> (C, n, H, W, ch) — materialization of that map
-    (``protos[labels] + noise``), pure jnp so it runs eagerly on the host
-    OR traced inside a jitted program;
+  * ``client_images``     -> (C, n, D), D = H*W*ch — materialization of that
+    map (``protos[labels] + noise``) as one lane-dense feature-minor row
+    per sample, pure jnp so it runs eagerly on the host OR traced inside a
+    jitted program;
   * ``partition_clients`` -> both, the legacy one-call API.
 
 Every function here is a pure function of (key, static config, traced
 ``regions``), which is what lets the batched engine build client shards
 ON DEVICE inside its compiled grid program (``repro.fl.rounds
-.make_round_data``) instead of host-materializing one (C, n, H, W, ch)
+.make_round_data``) instead of host-materializing one (C, n, D)
 copy per data row — grids then scale past host RAM: the host only ever
 stacks per-experiment PRNG keys (under device-resident init even the
 (C,) region ids are re-derived in-program from the twin spawn).  Data
@@ -94,21 +95,25 @@ def partition_labels(key, dataset: str, cfg: FLConfig, regions=None) -> jax.Arra
 
 
 def client_images(key, dataset: str, labels: jax.Array) -> jax.Array:
-    """Materialize (C, n, H, W, ch) images from a (C, n) label index map.
+    """Materialize (C, n, D) sample rows from a (C, n) label index map.
 
     ``protos[labels] + noise`` with prototypes shared across clients and
     noise per-client; deterministic in (key, labels), so the host path and
-    the on-device path produce identical arrays.
+    the on-device path produce identical arrays.  Each sample is one
+    contiguous row of D = H*W*ch features (the row-major flattening of its
+    image), so on the chip the features lie in the lanes and a training
+    step gathers its batch as whole rows (``fl.client``).
     """
     spec = dataset_spec(dataset)
     C, n = labels.shape
     kd = fold_in_str(key, f"data/{dataset}")
     protos = class_prototypes(kd, spec)  # shared across clients
     kn = jax.random.split(fold_in_str(kd, "noise"), C)
+    D = protos[0].size
     noise = jax.vmap(
-        lambda kk: spec.noise * jax.random.normal(kk, (n, *spec.shape))
+        lambda kk: spec.noise * jax.random.normal(kk, (n, D))
     )(kn)
-    return protos[labels] + noise
+    return protos.reshape(-1, D)[labels] + noise
 
 
 def client_sample_counts(labels: jax.Array) -> jax.Array:
@@ -142,7 +147,7 @@ def rsu_sample_mass(weights: jax.Array, rid: jax.Array, n_rsu: int) -> jax.Array
 
 
 def partition_clients(key, dataset: str, cfg: FLConfig, regions=None):
-    """Returns (images (C,n,H,W,ch), labels (C,n)) for all C clients.
+    """Returns (images (C, n, H*W*ch) rows, labels (C, n)) for all C clients.
 
     ``regions``: optional (C,) road-region ids enabling geographic non-iid.
     """

@@ -65,8 +65,9 @@ Shape conventions (docs/architecture.md has the full walkthrough):
     round-trips the pytree) until the single FedAvg reduction;
   * ``RoundData`` rows may carry a leading dedup-row axis: passing
     ``data_idx`` makes every access gather ``leaf[data_idx, ...]`` lazily
-    (one fused gather at the use site), so the batched engine shares one
-    stacked row set across lanes without materializing per-lane copies;
+    at the use site (each SGD step's batch by ``(data_idx, client,
+    sample)``), so the batched engine shares one stacked row set across
+    lanes without materializing per-lane copies;
   * every ``RoundState``/``RoundData``/``RoundMetrics`` leaf gains a
     LEADING grid axis (G, ...) under the batched engine — per-experiment
     code never indexes it, ``vmap``/``shard_map`` insert it.
@@ -200,7 +201,7 @@ class RoundData(NamedTuple):
     partitioner that fills clients unevenly weights them honestly.
     """
 
-    images: jax.Array  # (N, n, H, W, C)
+    images: jax.Array  # (N, n, D) sample rows, D = H*W*C features minor
     labels: jax.Array  # (N, n)
     counts: jax.Array  # (N,) f32 per-client sample counts (FedAvg weights)
     test_x: jax.Array
@@ -438,24 +439,28 @@ def make_warmup(loss_fn, fl: FLConfig, param_spec):
     def warmup(state: RoundState, data: RoundData, data_idx=None) -> RoundState:
         bs = fl.batch_size
         params = unflatten_from_vector(state.params, param_spec)
-        # slice each client's first batch BEFORE the lane's row gather: a
-        # gather of whole rows materializes every client's full shard per lane
+        # each client trains on its first batch: the trainer's step gathers
+        # its rows from this slice of the store, which every lane shares, so
+        # no lane copies a client's shard
         axis = 1 if data_idx is None else 2
-        first = lambda leaf: _row(
-            jax.lax.slice_in_dim(leaf, 0, bs, axis=axis), data_idx
-        )
-        images, labels = first(data.images), first(data.labels)
-        keys = jax.random.split(fold_in_str(state.key, "warmup"), images.shape[0])
+        images = jax.lax.slice_in_dim(data.images, 0, bs, axis=axis)
+        labels = jax.lax.slice_in_dim(data.labels, 0, bs, axis=axis)
+        lead = () if data_idx is None else (data_idx,)
+        N = labels.shape[axis - 1]
+        keys = jax.random.split(fold_in_str(state.key, "warmup"), N)
 
         def sketch_one(x):
-            vec = one_step(params, x[0][None], x[1][None], x[2][None])[1][0]
+            c, k = x
+            vec = one_step(
+                params, images, labels, k[None], rows=(*lead, c[None])
+            )[1][0]
             return apply_sketch(vec, state.sketch_sign, fl.sketch_dim)
 
         # all N clients train and sketch in chunks of the cohort width, so
         # the bootstrap's peak memory is a round's: neither N clients'
         # activations nor their (N, P) update vectors exist at once
         sketches = jax.lax.map(
-            sketch_one, (images, labels, keys), batch_size=fl.n_select
+            sketch_one, (jnp.arange(N), keys), batch_size=fl.n_select
         )
         k_km = fold_in_str(jax.random.fold_in(state.key, 0), "kmeans")
         clusters, _ = kmeans_cluster(sketches, k_km, fl.num_clusters)
@@ -649,12 +654,13 @@ def make_round_step(loss_fn, fl: FLConfig, cohort_size: int, model_bytes: float,
             n_selected = jnp.sum(mask).astype(jnp.int32)
 
         with stage("train"):
-            # ---- fixed-size cohort gather ----------------------------------
+            # ---- fixed-size cohort slots -----------------------------------
             # Selected client ids in ascending order fill the first slots; the
             # rest are no-op padding (zeroed data + zeroed updates) — never a
-            # redundant retraining of client 0.  Under a stacked ``data`` the
-            # row and cohort gathers fuse into ONE (data_idx, idx_c) gather per
-            # leaf — no per-lane copy of the full client shard.
+            # redundant retraining of client 0.  The trainer gathers each SGD
+            # batch straight from the store by (data_idx, idx_c[k], sample):
+            # neither a per-lane copy of the client shards nor the cohort's
+            # (K, n, D) block is ever made.
             order = jnp.where(mask, jnp.arange(N), N + jnp.arange(N))
             idx = jnp.sort(order)[:K]
             slot_valid = idx < N
@@ -787,6 +793,7 @@ def make_round_step(loss_fn, fl: FLConfig, cohort_size: int, model_bytes: float,
         with stage("train"):
             # ---- local training + edge reduce ------------------------------
             params = unflatten_from_vector(state.params, param_spec)
+            lead = () if data_idx is None else (data_idx,)
             if client_block:
                 # chunk-streamed two-tier lane: an inner scan trains fixed-size
                 # client chunks and segment-reduces each straight into (R, P)
@@ -831,16 +838,8 @@ def make_round_step(loss_fn, fl: FLConfig, cohort_size: int, model_bytes: float,
                     else:
                         partials, sketches, sketch_age = carry
                         i_c, v_c, w_c, r_c, ok_c, k_c = xs_c
-                    if data_idx is None:
-                        imgs_c = data.images[i_c]
-                        lbls_c = data.labels[i_c]
-                    else:
-                        imgs_c = data.images[data_idx, i_c]
-                        lbls_c = data.labels[data_idx, i_c]
-                    dm = v_c.reshape((B,) + (1,) * (imgs_c.ndim - 1))
-                    imgs_c = imgs_c * dm
-                    lbls_c = jnp.where(v_c[:, None], lbls_c, 0)
-                    _, vb = trainer(params, imgs_c, lbls_c, k_c)
+                    _, vb = trainer(params, data.images, data.labels, k_c,
+                                    rows=(*lead, i_c), valid=v_c)
                     vb = vb * v_c[:, None]
                     if half:
                         # the comm lane: chunk deltas travel (and park in the
@@ -883,15 +882,9 @@ def make_round_step(loss_fn, fl: FLConfig, cohort_size: int, model_bytes: float,
                 red, red_w, bp = partials, live.astype(jnp.float32), \
                     pick_block_p(R + buf_rows, P, itemsize=itemsize)
             else:
-                if data_idx is None:
-                    imgs, lbls = data.images[idx_c], data.labels[idx_c]
-                else:
-                    imgs = data.images[data_idx, idx_c]
-                    lbls = data.labels[data_idx, idx_c]
-                dmask = slot_valid.reshape((K,) + (1,) * (imgs.ndim - 1))
-                imgs = imgs * dmask
-                lbls = jnp.where(slot_valid[:, None], lbls, 0)
-                _, vecs = trainer(params, imgs, lbls, fold_in_str(rk, "local"))
+                _, vecs = trainer(params, data.images, data.labels,
+                                  fold_in_str(rk, "local"),
+                                  rows=(*lead, idx_c), valid=slot_valid)
                 vecs = vecs * slot_valid[:, None]
                 if half:
                     # the comm lane: update vectors travel to the reduce (and

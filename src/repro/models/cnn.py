@@ -55,7 +55,12 @@ def init_cnn(key, cfg) -> dict:
 
 
 def cnn_logits(params, cfg, images):
-    """images (B,H,W,C) -> logits (B, num_classes).
+    """images (B,H,W,C), or (B, H*W*C) feature-minor rows -> logits
+    (B, num_classes).
+
+    Rows (the FL client store's layout, ``fl.partition.client_images``)
+    are reshaped to ``cfg.image_shape`` first; for the MLP that reshape
+    folds into its flatten.
 
     Activations follow the PARAM dtype (the fc2 leaf, representative of
     the whole tree): fp32 masters run the historical fp32 forward; the FL
@@ -63,7 +68,8 @@ def cnn_logits(params, cfg, images):
     / matmuls run half-width end to end (``fl.client.make_local_trainer``
     holds loss and gradients in fp32).
     """
-    x = images.astype(params["fc2"]["w"].dtype)
+    x = images.reshape(images.shape[0], *cfg.image_shape)
+    x = x.astype(params["fc2"]["w"].dtype)
     for conv in params["convs"]:
         x = jax.lax.conv_general_dilated(
             x, conv["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
@@ -81,7 +87,7 @@ def cnn_logits(params, cfg, images):
 
 
 def cnn_loss(params, cfg, batch):
-    """batch: images (B,H,W,C), labels (B,).
+    """batch: images (B,H,W,C) or (B, H*W*C) rows, labels (B,).
 
     The cross-entropy accumulates in fp32 whatever the forward dtype (the
     logsumexp upcast is exact for bf16 logits and a no-op for fp32).

@@ -7,12 +7,17 @@ as the grid vmaps it, through the TPU compiler for a chip that is
 described and not attached, and find its ``tpu_custom_call`` in the
 compiled text.  They run on the CPU host; nothing executes.
 
+One test compiles a whole 2-lane grid program at the benchmark cell's
+per-lane shapes and reads the SGD step loop of the compiled text.
+
 The topology is described inside a fixture (never at import or
 collection), so every test worker collects the same tests and only the
 worker that runs this file loads the TPU compiler.
 """
 import importlib.util
+import math
 import os
+import re
 import sys
 
 import jax
@@ -155,3 +160,104 @@ def test_fedavg_reduce_compiles(smoke, one_chip, rows):
     text = _compiled_text(fn, one_chip, _shape(G, R, P, dtype=rows),
                           _shape(G, R))
     assert "tpu_custom_call" in text
+
+
+# ---- the grid program's SGD step, as the chip's compiler lays it out ----------
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(")
+_CALLS = re.compile(r"(?:calls|body|condition|to_apply)=(%[\w.\-]+)"
+                    r"|(?:branch_computations|called_computations)=\{([^}]*)\}")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\](?:\{([\d,]*))?")
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation: [(name, shape, opcode, callees, line)]}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line.startswith("ENTRY ") or (line.startswith("%")
+                                          and line.rstrip().endswith("{")):
+            cur = line.split()[1 if line.startswith("ENTRY ") else 0]
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and (m := _INSTR.match(line)):
+            callees = []
+            for one, many in _CALLS.findall(line):
+                callees += [one] if one else [c.strip() for c in many.split(",")]
+            comps[cur].append((m.group(1), m.group(2), m.group(3), callees, line))
+    return comps
+
+
+def _array(shape):
+    """(dims, minor-to-major layout) of an array shape, (None, None) else."""
+    m = _ARRAY.match(shape)
+    if not m:
+        return None, None
+    dims = [int(d) for d in m.group(1).split(",") if d]
+    layout = [int(d) for d in m.group(2).split(",")] if m.group(2) else None
+    return dims, layout
+
+
+def _loop_body(comps, body):
+    """The computations one trip of a loop runs, nested loops left out."""
+    seen, todo = set(), [body]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += [x for i in comps[c] if i[2] != "while" for x in i[3]]
+    return seen
+
+
+def test_sgd_step_gathers_feature_minor_rows(one_chip):
+    """The `fl.train` SGD step of the cell's grid program (2 lanes, K = 10
+    clients of n = 512 MNIST samples, batch 64) gathers its batch as rows
+    with the D = 784 features minor, and nothing in the step loop is as
+    large as one lane's (K, n, D) cohort block."""
+    from repro.config import FLConfig
+    from repro.configs import get_config
+    from repro.fl.engine import ExperimentEngine, _eval_flags, _recluster_flags
+    from repro.fl.rounds import experiment_key
+
+    fl = FLConfig()
+    G, K, n, bs, D = 2, fl.n_select, fl.samples_per_client, fl.batch_size, 784
+    eng = ExperimentEngine(get_config("fl-mnist-mlp"), fl, "mnist",
+                           strategies=("contextual",))
+    eng._ensure_spec()
+    scns = stack_scenarios([
+        scenario_params(scenario_config(s, num_vehicles=fl.num_clients))
+        for s in ("ring", "rush_hour")
+    ])
+    keys = jnp.stack([experiment_key("mnist", "contextual", 0)] * G)
+    lane = jnp.arange(G, dtype=jnp.int32)
+    args = (keys, (keys, scns), scns, lane * 0, lane * 0, lane,
+            (_eval_flags(10, 5), _recluster_flags(10, fl.recluster_every)))
+    sds = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                       sharding=one_chip),
+        args,
+    )
+    with jax.default_matmul_precision("highest"):
+        text = eng._grid_fn.lower(*sds, warm=True).compile().as_text()
+    comps = _computations(text)
+
+    gathers = [(c, i) for c, ins in comps.items() for i in ins
+               if i[2] == "gather" and "fl.train" in i[4]
+               and i[1].startswith("f32")
+               and math.prod(_array(i[1])[0] or [0]) == G * K * bs * D]
+    assert len(gathers) == 1, [i[:3] for _, i in gathers]
+    (where, gather), = gathers
+    dims, layout = _array(gather[1])
+    assert dims[layout[0]] == D, gather[1]
+
+    loops = [i for ins in comps.values() for i in ins if i[2] == "while"
+             and where in _loop_body(comps, re.search(
+                 r"body=(%[\w.\-]+)", i[4]).group(1))]
+    assert len(loops) == 1, [i[:3] for i in loops]
+    step = _loop_body(comps, re.search(r"body=(%[\w.\-]+)",
+                                       loops[0][4]).group(1))
+    views = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    large = [i[:3] for c in step for i in comps[c] if i[2] not in views
+             and math.prod(_array(i[1])[0] or [0]) >= K * n * D]
+    assert not large, large
