@@ -49,6 +49,8 @@ def readings(cell, devices, seeds: int, base_seed: int, control: bool, faults,
         low = {**cell, "cfg": {**cell["cfg"], "matmul_precision": below}}
         low_eng = bench_run.build_engine(low, devices)
     rounds = mix["check_rounds"]
+    scale = check.loss_scale(cell)
+    test_samples = cell["model"].eval_samples(cell["cfg"]["shapes"])
     out = {"program": []}
     for i in range(seeds):
         t0 = time.perf_counter()
@@ -78,7 +80,7 @@ def readings(cell, devices, seeds: int, base_seed: int, control: bool, faults,
             for f in faults:
                 runs[f] = reference.run_lane(lane._replace(fault=f), p.seed,
                                              rounds)._asdict()
-            row = {k: check.gaps(m, ref, rounds) for k, m in runs.items()}
+            row = {k: check.gaps(m, ref, rounds, scale) for k, m in runs.items()}
             for k, v in row.items():  # a lane with no decided round reads nothing
                 if check.decided_rounds(ref, rounds):
                     out.setdefault(k, []).append(v)
@@ -87,7 +89,7 @@ def readings(cell, devices, seeds: int, base_seed: int, control: bool, faults,
                               "decided": check.decided_rounds(ref, rounds), **row}),
                   flush=True)
             if detail:
-                print(json.dumps({"detail": {k: check.detail(m, ref)
+                print(json.dumps({"detail": {k: check.detail(m, ref, test_samples)
                                              for k, m in runs.items()}}), flush=True)
     return out
 

@@ -16,8 +16,9 @@ those lanes and over the rounds the reference calls decided
 - `count_gap`: absolute gap of the counts (selected, succeeded, parked in
   and drained from the fedbuff ring);
 - `loss_gap`: gap of the test loss on each eval round, over the reference's
-  loss or the loss of a model that knows nothing, ln(10), whichever is
-  larger: cohort training, the server reduce and rule, and the evaluation.
+  loss or the loss of a model that knows nothing (the client model's
+  `null_loss`, ln(10) for ten classes), whichever is larger: cohort
+  training, the server reduce and rule, and the evaluation.
   (A lane that has learned its task reads a loss near 0 from large logits,
   whose rounding is absolute: measured against that loss alone, two
   correct computations part by any share.)
@@ -41,8 +42,6 @@ import numpy as np
 from bench import reference
 
 COUNTS = ("n_selected", "n_succeeded", "n_buffered", "n_drained")
-TEST_IMAGES = reference.N_TEST
-LOSS_SCALE = float(np.log(reference.NUM_CLASSES))  # nats, an untrained model's loss
 
 
 @dataclasses.dataclass
@@ -78,11 +77,11 @@ def lane_of(cell: dict, label: tuple):
     """The reference's `Lane` of a picked lane, in the configuration's
     precisions."""
     cfg, mix = cell["cfg"], cell["mix"]
-    fl, shapes = cfg["fl"], cfg["shapes"]
+    fl = cfg["fl"]
     strategy, aggregator, _, scenario = label
     return reference.Lane(
-        cfg["dataset"], tuple(shapes["image_shape"]), tuple(shapes["channels"]),
-        shapes["d_ff"], tuple(sorted(fl.items())), tuple(mix["strategies"]),
+        cfg["dataset"], cell["model_file"], reference.pairs(cfg["shapes"]),
+        reference.pairs(fl), tuple(mix["strategies"]),
         tuple(mix["aggregators"]), mix["rounds"], mix["eval_every"], mix["warmup"],
         fl["compute_dtype"], fl["param_dtype"], matmul=cfg["matmul_precision"],
         strategy=strategy, aggregator=aggregator, scenario=scenario,
@@ -104,8 +103,14 @@ def decided_rounds(ref: reference.Metrics, rounds: int) -> int:
     return int(np.sum(np.asarray(ref.decided)[:rounds]))
 
 
-def gaps(prog: dict, ref: reference.Metrics, rounds: int) -> dict:
-    """The numbers of one lane (see the module docstring)."""
+def loss_scale(cell: dict) -> float:
+    """The loss of a model that knows nothing, in nats: `loss_gap`'s floor."""
+    return cell["model"].null_loss(cell["cfg"]["shapes"])
+
+
+def gaps(prog: dict, ref: reference.Metrics, rounds: int, scale: float) -> dict:
+    """The numbers of one lane (see the module docstring); ``scale`` is
+    `loss_scale`."""
     n = decided_rounds(ref, rounds)
     cut = lambda x: np.asarray(x, np.float64)[:n]
     return {
@@ -115,13 +120,14 @@ def gaps(prog: dict, ref: reference.Metrics, rounds: int) -> dict:
         "count_gap": float(max(np.max(np.abs(cut(prog[f]) - cut(getattr(ref, f))),
                                       initial=0.0)
                                for f in COUNTS)),
-        "loss_gap": _rel(cut(prog["test_loss"]), cut(ref.test_loss), LOSS_SCALE),
+        "loss_gap": _rel(cut(prog["test_loss"]), cut(ref.test_loss), scale),
     }
 
 
-def detail(prog: dict, ref: reference.Metrics) -> dict:
+def detail(prog: dict, ref: reference.Metrics, test_samples: int) -> dict:
     """Per field, the gap of each round: relative for the economics and the
-    loss, absolute for the counts and the accuracy (in test images)."""
+    loss, absolute for the counts and the accuracy (in test samples, of
+    ``test_samples``)."""
     out = {"decided": [bool(x) for x in ref.decided]}
     for f in ref._fields:
         if f == "decided":
@@ -131,7 +137,7 @@ def detail(prog: dict, ref: reference.Metrics) -> dict:
         if f in COUNTS:
             g = np.abs(a - b)
         elif f == "test_acc":
-            g = np.abs(a - b) * TEST_IMAGES
+            g = np.abs(a - b) * test_samples
         else:
             g = np.abs(a - b) / np.maximum(np.abs(b), 1e-30)
         out[f] = [float(x) for x in g]
@@ -151,7 +157,8 @@ def compare(cell: dict, picks) -> dict:
     print(f"check: rounds decided of {rounds}, by lane: {decided}", file=sys.stderr)
     if not any(decided):
         raise SystemExit("bench: the reference decided no round of any lane")
-    numbers = worst([gaps(p.metrics, ref, rounds) for p, ref in pairs])
+    scale = loss_scale(cell)
+    numbers = worst([gaps(p.metrics, ref, rounds, scale) for p, ref in pairs])
     unknown = set(cell["limits"]) - set(numbers)
     if unknown:
         raise SystemExit(f"bench: limits for unknown numbers {sorted(unknown)}")

@@ -10,40 +10,15 @@ a share honest and counting too much would push it past 100%.
 from __future__ import annotations
 
 F32 = 4
-TEST_IMAGES = 2_000  # the global test set every eval round classifies
-
-
-def forward_flops(image_shape, channels, d_ff, num_classes) -> int:
-    """Multiply-adds x 2 of one image through the client model.
-
-    3x3 SAME convolutions at full resolution, each followed by a 2x2 pool,
-    then two dense layers.  Biases, ReLU and pooling are not counted.
-    """
-    h, w, c = image_shape
-    flops = 0
-    for out_c in channels:
-        flops += 2 * h * w * 9 * c * out_c
-        c, h, w = out_c, h // 2, w // 2
-    flops += 2 * h * w * c * d_ff
-    flops += 2 * d_ff * num_classes
-    return flops
-
-
-def param_count(image_shape, channels, d_ff, num_classes) -> int:
-    h, w, c = image_shape
-    n = 0
-    for out_c in channels:
-        n += 9 * c * out_c + out_c
-        c, h, w = out_c, h // 2, w // 2
-    return n + h * w * c * d_ff + d_ff + d_ff * num_classes + num_classes
 
 
 def cohort_size(fl: dict) -> int:
     return max(int(round(fl["select_fraction"] * fl["num_clients"])), 1)
 
 
-def lane_model_flops(shapes: dict, fl: dict, traffic: dict) -> float:
-    """Client-model FLOPs of one lane's sweep.
+def lane_model_flops(model, shapes: dict, fl: dict, traffic: dict) -> float:
+    """Client-model FLOPs of one lane's sweep, from the forward FLOPs per
+    sample that ``model`` (a `bench/models` module) counts for ``shapes``.
 
     Forward and backward (3x forward) of every local SGD step of the
     cohort in every round, and of the warm-up bootstrap (one step of one
@@ -51,15 +26,15 @@ def lane_model_flops(shapes: dict, fl: dict, traffic: dict) -> float:
     pass of the test set on every eval round.  k-means, sketches and the
     geometry are not counted.
     """
-    fwd = forward_flops(shapes["image_shape"], shapes["channels"], shapes["d_ff"],
-                        shapes["num_classes"])
+    fwd = model.train_flops(shapes)
     bs, n = fl["batch_size"], fl["samples_per_client"]
     steps = fl["local_epochs"] * max(n // bs, 1)
     rounds = traffic["rounds"]
     train = rounds * cohort_size(fl) * steps * bs * 3 * fwd
     if traffic["warmup"]:
         train += fl["num_clients"] * min(bs, n) * 3 * fwd
-    return float(train + eval_rounds(traffic) * TEST_IMAGES * fwd)
+    return float(train + eval_rounds(traffic) * model.eval_samples(shapes)
+                 * model.eval_flops(shapes))
 
 
 def eval_rounds(traffic: dict) -> int:

@@ -6,7 +6,9 @@ of the paper's four-stage selector and the round economics: a ring-road
 traffic twin, CAM/CPM observations and their inverse-variance fusion, the
 kinematic predictor, nearest-RSU attachment and the Shannon latency model,
 the election strategies, cohort SGD, FedAvg / FedAdam / FedBuff server
-rules, sketch k-means and the test evaluation.
+rules, sketch k-means and the test evaluation.  The client model, its data
+and its loss are the module of `bench/models/` that the lane names; no
+line here knows what the model is.
 
 It imports nothing of the program under test.  It runs one lane with no
 vmap, no sharding, no Pallas kernel, no chunked cohort and no two-tier
@@ -33,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import models
+
 # Scenario definitions: the traffic and radio constants of each named road.
 _ROAD = dict(
     ring_length_m=10_000.0, rsu_spacing_m=1_000.0, mean_speed_mps=14.0,
@@ -51,13 +55,6 @@ SCENARIOS = {
     ),
 }
 
-# Synthetic image datasets: (H, W, C), sample noise std, prototype scale.
-DATASETS = {
-    "mnist": ((28, 28, 1), 0.85, 1.0),
-    "cifar10": ((32, 32, 3), 1.60, 1.0),
-}
-NUM_CLASSES = 10
-N_TEST = 2_000
 N_REGIONS = 10
 TWIN_SUBSTEPS = 15  # equal sub-steps per twin advance
 KMEANS_ITERS = 25
@@ -108,56 +105,12 @@ def experiment_key(dataset: str, strategy: str, seed: int):
     return fold_in_str(jax.random.key(seed), f"fl-sim/{strategy}/{dataset}")
 
 
-# ---- the client model -------------------------------------------------------
+# ---- the payload --------------------------------------------------------------
 
-def init_model(key, image_shape, channels, d_ff):
-    """Truncated-normal fan-in init; returns the leaves in flat order."""
-    H, W, C = image_shape
-    ks = jax.random.split(key, 2 + 2 * max(len(channels), 1))
-    tn = lambda k, shape, fan: (1.0 / np.sqrt(fan)) * jax.random.truncated_normal(
-        k, -2.0, 2.0, shape, jnp.float32)
-    leaves, in_c, h, w = [], C, H, W
-    for i, out_c in enumerate(channels):
-        leaves += [jnp.zeros((out_c,)), tn(ks[i], (3, 3, in_c, out_c), 9 * in_c)]
-        in_c, h, w = out_c, h // 2, w // 2
-    flat = h * w * in_c
-    leaves += [jnp.zeros((d_ff,)), tn(ks[-2], (flat, d_ff), flat)]
-    leaves += [jnp.zeros((NUM_CLASSES,)), tn(ks[-1], (d_ff, NUM_CLASSES), d_ff)]
-    return leaves
-
-
-def _rounder(dtype: str):
-    """Value rounding of the model's forward pass in ``dtype``."""
-    if dtype == "float32":
-        return lambda x: x.astype(jnp.float32)
-    if dtype == "bfloat16":
-        return lambda x: x.astype(jnp.bfloat16)
-    raise ValueError(f"unknown model dtype {dtype!r}")
-
-
-def logits_fn(leaves, images, n_convs, dtype):
-    r = _rounder(dtype)
-    x = r(images)
-    p = [r(a) for a in leaves]
-    for i in range(n_convs):
-        b, w = p[2 * i], p[2 * i + 1]
-        x = jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = r(jax.nn.relu(r(x) + b))
-        x = jax.lax.reduce_window(x, np.array(-np.inf, x.dtype), jax.lax.max,
-                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    b1, w1, b2, w2 = p[-4:]
-    x = r(jax.nn.relu(r(x @ w1) + b1))
-    return (r(x @ w2) + b2).astype(jnp.float32)
-
-
-def loss_fn(leaves, images, labels, n_convs, dtype):
-    logits = logits_fn(leaves, images, n_convs, dtype)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
-    return jnp.mean(logz - gold), acc
+def pairs(d: dict) -> tuple:
+    """``d`` as sorted (key, value) pairs, lists made tuples: a `Lane` field."""
+    freeze = lambda v: tuple(freeze(x) for x in v) if isinstance(v, list) else v
+    return tuple(sorted((k, freeze(v)) for k, v in d.items()))
 
 
 def flatten(leaves):
@@ -170,28 +123,6 @@ def unflatten(vec, like):
         out.append(vec[off:off + a.size].reshape(a.shape))
         off += a.size
     return out
-
-
-# ---- data -------------------------------------------------------------------
-
-def client_data(key, dataset, regions, n, classes_per_client):
-    (H, W, C), noise, scale = DATASETS[dataset]
-    kd = fold_in_str(key, f"data/{dataset}")
-    protos = scale * jax.random.normal(fold_in_str(kd, f"proto/{dataset}"),
-                                       (NUM_CLASSES, H, W, C))
-    k = classes_per_client
-    own = jnp.mod(regions[:, None] + jnp.arange(k)[None, :], NUM_CLASSES)
-    kl = jax.random.split(fold_in_str(kd, "labels"), regions.shape[0])
-    pick = jax.vmap(lambda kk: jax.random.randint(kk, (n,), 0, k))(kl)
-    labels = jnp.take_along_axis(own, pick, axis=1)
-    kn = jax.random.split(fold_in_str(kd, "noise"), regions.shape[0])
-    images = protos[labels] + jax.vmap(
-        lambda kk: noise * jax.random.normal(kk, (n, H, W, C)))(kn)
-    kt = fold_in_str(kd, "test")
-    test_y = jax.random.randint(fold_in_str(kt, "labels"), (N_TEST,), 0, NUM_CLASSES)
-    test_x = protos[test_y] + noise * jax.random.normal(
-        fold_in_str(kt, "noise"), (N_TEST, H, W, C))
-    return images, labels, test_x, test_y
 
 
 # ---- the traffic twin and the radio ------------------------------------------
@@ -416,9 +347,8 @@ class Lane(NamedTuple):
     (one compiled program serves every lane of a cell) and the lane's own."""
 
     dataset: str
-    image_shape: tuple
-    channels: tuple
-    d_ff: int
+    client_model: str  # the path of the client model's bench/models/<name>.py
+    shapes: tuple  # sorted (key, value) pairs of the client model's shapes
     fl: tuple  # sorted (field, value) pairs of the FL configuration
     strategies: tuple  # the cell's strategies ...
     aggregators: tuple  # ... and server rules
@@ -435,16 +365,19 @@ class Lane(NamedTuple):
     fault: str = ""  # a planted fault: "frozen" rounds or "half" the cohort
 
 
-def local_sgd(leaves, images, labels, key, fl, epochs, n_convs, dtype):
-    n, bs = images.shape[0], fl["batch_size"]
+def local_sgd(leaves, x, y, key, fl, epochs, loss):
+    """Plain SGD of ``loss(leaves, x, y) -> (loss, ...)`` over the sample
+    rows of one vehicle's ``x`` and ``y``, in batches of a fresh
+    permutation each epoch."""
+    n, bs = x.shape[0], fl["batch_size"]
     spe = max(n // bs, 1)
     keys = jax.random.split(key, epochs)
     order = jnp.concatenate([jax.random.permutation(k, n)[: spe * bs] for k in keys])
-    grad = jax.grad(lambda p, x, y: loss_fn(p, x, y, n_convs, dtype)[0])
+    grad = jax.grad(lambda p, xb, yb: loss(p, xb, yb)[0])
 
     def step(i, leaves):
         b = jax.lax.dynamic_slice_in_dim(order, i * bs, bs)
-        g = grad(leaves, images[b], labels[b])
+        g = grad(leaves, x[b], y[b])
         return [w - fl["learning_rate"] * gw for w, gw in zip(leaves, g)]
 
     return jax.lax.fori_loop(0, epochs * spe, step, leaves)
@@ -472,10 +405,9 @@ def _programs(lane: Lane, R: int, steps: int, dt: float, horizon: float):
         if fl[k] != v:
             raise ValueError(f"the reference runs {k}={v!r} only, not {fl[k]!r}")
     N, K = fl["num_clients"], max(int(round(fl["select_fraction"] * fl["num_clients"])), 1)
-    n_convs = len(lane.channels)
+    model, shapes = models.load(lane.client_model), dict(lane.shapes)
     half = fl["compute_dtype"] == "bfloat16"  # updates travel in bf16
-    like = jax.eval_shape(lambda k: init_model(k, lane.image_shape, lane.channels,
-                                               lane.d_ff), jax.random.key(0))
+    like = jax.eval_shape(lambda k: model.init(k, shapes), jax.random.key(0))
     P = sum(a.size for a in like)
     D = fl["sketch_dim"]
     model_bytes = 4.0 * P * (0.5 if half else 1.0)
@@ -493,20 +425,20 @@ def _programs(lane: Lane, R: int, steps: int, dt: float, horizon: float):
 
     def init(key, s):
         s = {k: v.astype(g) for k, v in s.items()}
-        leaves = init_model(fold_in_str(key, "model-init"), lane.image_shape,
-                            lane.channels, lane.d_ff)
+        leaves = model.init(fold_in_str(key, "model-init"), shapes)
         bits = jax.random.bernoulli(fold_in_str(fold_in_str(key, "selector"), "sketch-sign"),
                                     0.5, (P + (-P) % D,))
         twin = init_twin(fold_in_str(fold_in_str(key, "traffic-twin"), "init"), s, N)
         regions = jnp.floor(twin.pos / s["ring_length_m"] * N_REGIONS).astype(
             jnp.int32) % N_REGIONS
-        data = client_data(key, lane.dataset, regions, fl["samples_per_client"],
-                           fl["classes_per_client"])
+        data = model.client_data(key, lane.dataset, shapes, regions,
+                                 fl["samples_per_client"], fl["classes_per_client"])
         return flatten(leaves), jnp.where(bits, 1.0, -1.0), twin, data
 
     def update(params, x, y, key, epochs):
         leaves = unflatten(params, like)
-        new = local_sgd(leaves, x, y, key, fl, epochs, n_convs, lane.train_dtype)
+        new = local_sgd(leaves, x, y, key, fl, epochs,
+                        lambda p, xb, yb: model.loss(p, xb, yb, shapes, lane.train_dtype))
         return flatten(new) - params
 
     def warm(params, sign, key, data):
@@ -524,7 +456,7 @@ def _programs(lane: Lane, R: int, steps: int, dt: float, horizon: float):
         s = {k: v.astype(g) for k, v in s.items()}
         (params, m, v, twin, sks, clusters, buf, buf_arrive, buf_sent,
          buf_w, buf_mask, sim_time, sign) = st
-        images, labels, test_x, test_y = data
+        x, y, test_x, test_y = data
         is_adam, is_buff = aidx == rule("fedadam"), aidx == rule("fedbuff")
         rk = jax.random.fold_in(key, r)
         # stages 1-2: fuse the observations, predict, price the links
@@ -561,7 +493,7 @@ def _programs(lane: Lane, R: int, steps: int, dt: float, horizon: float):
         w = w / jnp.maximum(jnp.sum(w), 1e-9)
         # cohort training; survivors report sketches
         keys = jax.random.split(fold_in_str(rk, "local"), K)
-        vecs = jax.vmap(lambda i, k: update(params, images[i], labels[i], k,
+        vecs = jax.vmap(lambda i, k: update(params, x[i], y[i], k,
                                             fl["local_epochs"]))(idx, keys)
         vecs = vecs * valid[:, None]
         if half:
@@ -620,8 +552,8 @@ def _programs(lane: Lane, R: int, steps: int, dt: float, horizon: float):
         nan = jnp.float32(jnp.nan)
         loss, acc = jax.lax.cond(
             do_eval,
-            lambda: loss_fn(unflatten(params, like), test_x, test_y, n_convs,
-                            lane.eval_dtype),
+            lambda: model.loss(unflatten(params, like), test_x, test_y, shapes,
+                               lane.eval_dtype),
             lambda: (nan, nan))
         sim_time = sim_time + duration
         some = nsel > 0

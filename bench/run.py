@@ -3,8 +3,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of `workloads` in BENCHMARK.json.  It names a
-configuration (a file under bench/configs/: the model, every FLConfig
-field, the shapes, the precision of matrix products) and a traffic mix
+configuration (a file under bench/configs/: the model, its client-model
+module under bench/models/ and the shapes, every FLConfig field, the
+precision of matrix products) and a traffic mix
 (bench/workloads/<traffic>.json: the grid axes, rounds per sweep, eval
 cadence, warm-up, and how many lanes the correctness check samples).  A run:
 
@@ -33,9 +34,9 @@ T_START = time.perf_counter()  # set-up time runs from here
 
 import argparse  # noqa: E402
 import hashlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -48,7 +49,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import check, reference, trace  # noqa: E402
+from bench import check, models, reference, trace  # noqa: E402
 
 TRACED_SWEEPS = 2
 GIB = 2.0 ** 30
@@ -62,7 +63,8 @@ def load_json(path):
 
 
 def load_cell(name: str, root: str = ROOT) -> dict:
-    """The cell's entry, configuration, traffic, limits and metric specs."""
+    """The cell's entry, configuration, client model, traffic, limits and
+    metric specs."""
     spec = load_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -71,6 +73,14 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     configs = {c["name"]: c for c in spec["configs"]}
     cell["config_spec"] = configs[cell["config"]]
     cell["cfg"] = load_json(os.path.join(root, configs[cell["config"]]["file"]))
+    family = cell["cfg"].get("client_model")
+    if not isinstance(family, str) or not re.fullmatch(r"\w+", family):
+        raise SystemExit(f"bench: configuration {cell['config']!r} names no client "
+                         f"model (`client_model`: {family!r})")
+    cell["model_file"] = os.path.join(root, "bench", "models", family + ".py")
+    if not os.path.isfile(cell["model_file"]):
+        raise SystemExit(f"bench: no client model bench/models/{family}.py")
+    cell["model"] = models.load(cell["model_file"])
     cell["mix"] = load_json(os.path.join(root, "bench", "workloads",
                                          cell["traffic"] + ".json"))
     limits = os.path.join(root, "bench", "limits", name + ".json")
@@ -84,11 +94,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
 
 def metric_reader(name: str, root: str = ROOT):
     """`read(ctx)` of bench/metrics/<name>.py."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return models.load(os.path.join(root, "bench", "metrics", name + ".py")).read
 
 
 def sweep_seed(seed: int, index: int) -> int:
@@ -127,21 +133,26 @@ def check_device(chips: int):
 
 # ---- the system under test ---------------------------------------------------
 
+def model_config(cell: dict):
+    """The program's `ModelConfig` of the cell, held by the client model's
+    shape check to what the configuration states."""
+    from repro.configs import get_config
+
+    model = get_config(cell["cfg"]["model"])
+    try:
+        cell["model"].check_shapes(model, cell["cfg"]["shapes"])
+    except ValueError as e:
+        raise SystemExit(f"bench: {e}") from None
+    return model
+
+
 def build_engine(cell: dict, devices):
     from repro.config import FLConfig
-    from repro.configs import get_config
     from repro.fl.engine import ExperimentEngine
     from repro.launch.mesh import make_grid_mesh
 
     cfg, mix = cell["cfg"], cell["mix"]
-    model = get_config(cfg["model"])
-    shapes = cfg["shapes"]
-    got = (list(model.image_shape), list(model.channels), model.d_ff, model.num_classes)
-    want = (shapes["image_shape"], shapes["channels"], shapes["d_ff"],
-            shapes["num_classes"])
-    if got != want:
-        raise SystemExit(f"bench: {cfg['model']} has shapes {got}, the "
-                         f"configuration states {want}")
+    model = model_config(cell)
     mesh = make_grid_mesh(len(devices)) if len(devices) > 1 else None
     return ExperimentEngine(
         model, FLConfig(**cfg["fl"]), cfg["dataset"],
@@ -171,8 +182,10 @@ class Recorder:
         if self.args is None:
             self.precision = jax.config.jax_default_matmul_precision
             self.args = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=getattr(x, "sharding", None)),
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype,
+                    # an uncommitted argument goes where the program puts it
+                    sharding=x.sharding if getattr(x, "committed", False) else None),
                 args)
             self.kwargs = kwargs
         return self.fn(*args, **kwargs)
@@ -300,6 +313,7 @@ def run(args, root: str = ROOT) -> dict:
         done, host_s, red = traced_window(eng, cell, args.seed, rec)
         ctx = {"trace": red, "sweeps": len(done), "run_grid_s": host_s,
                "chips": len(devices), "peak": peaks[kind], "cfg": cell["cfg"],
+               "model": cell["model"],
                "mix": mix, "lanes": lanes, "scenarios": reference.SCENARIOS}
         for m in cell["per_layer"]:
             value = metric_reader(m["name"], root)(ctx)

@@ -1,4 +1,5 @@
-"""The benchmark's operation and byte counts against hand counts."""
+"""The benchmark's operation and byte counts against hand counts, the
+client model's through its module (bench/models/image_classifier.py)."""
 import json
 import os
 import sys
@@ -8,7 +9,9 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import flops  # noqa: E402
+from bench import flops, models  # noqa: E402
+
+IMAGE = models.load(os.path.join(ROOT, "bench", "models", "image_classifier.py"))
 
 CNN = dict(image_shape=(32, 32, 3), channels=(32, 64), d_ff=256, num_classes=10)
 MLP = dict(image_shape=(28, 28, 1), channels=(), d_ff=200, num_classes=10)
@@ -29,19 +32,28 @@ def test_cnn_forward_flops_hand_count():
     # conv1 32x32x(3*9)x32, conv2 16x16x(32*9)x64, fc 4096x256, fc 256x10
     hand = 2 * (32 * 32 * 27 * 32 + 16 * 16 * 288 * 64 + 4096 * 256 + 256 * 10)
     assert hand == 13_308_928
-    assert flops.forward_flops(**CNN) == hand
+    assert IMAGE.train_flops(CNN) == IMAGE.eval_flops(CNN) == hand
+
+
+def _payload_size(model, shapes):
+    """P: the size of the leaves the client model's `init` gives."""
+    import jax
+
+    leaves = jax.eval_shape(lambda k: model.init(k, shapes), jax.random.key(0))
+    return sum(a.size for a in leaves)
 
 
 @pytest.mark.parametrize("shapes, params", [(MLP, 159_010), (CNN, P_CNN)])
 def test_param_counts(shapes, params):
-    assert flops.param_count(**shapes) == params
+    assert _payload_size(IMAGE, shapes) == params
 
 
 @pytest.mark.parametrize("config", ["mnist-mlp-paper"])
 def test_config_states_the_param_count_of_its_shapes(config):
-    s = _cfg(config)["shapes"]
-    assert flops.param_count(s["image_shape"], s["channels"], s["d_ff"],
-                             s["num_classes"]) == s["params"]
+    """`params`, P, is the size of what the client model's `init` gives."""
+    cfg = _cfg(config)
+    model = models.load(os.path.join(ROOT, "bench", "models", cfg["client_model"] + ".py"))
+    assert _payload_size(model, cfg["shapes"]) == cfg["shapes"]["params"]
 
 
 @pytest.mark.parametrize("rule, nbytes", [
@@ -62,7 +74,7 @@ def test_paper_lane_model_flops_hand_count():
     train = 5 * 10 * (512 // 64) * 64 * 3 * fwd  # 5 rounds x 10 clients x 8 steps
     warm = 100 * 64 * 3 * fwd  # one batch on every vehicle
     ev = 5 * 2_000 * fwd  # an eval every round
-    got = flops.lane_model_flops(shapes, fl, mix)
+    got = flops.lane_model_flops(IMAGE, shapes, fl, mix)
     assert got == train + warm + ev == 1_410_746_368_000
 
 
@@ -72,7 +84,7 @@ def test_mlp_paper_lane_model_flops_hand_count():
     train = 10 * 10 * 8 * 64 * 3 * fwd  # 10 rounds x 10 clients x 8 steps of 64
     warm = 100 * 64 * 3 * fwd  # one batch on every vehicle
     ev = 2 * 2_000 * fwd  # evals after rounds 5 and 10
-    assert flops.lane_model_flops(cfg["shapes"], cfg["fl"], mix) == train + warm + ev
+    assert flops.lane_model_flops(IMAGE, cfg["shapes"], cfg["fl"], mix) == train + warm + ev
 
 
 @pytest.mark.parametrize("rounds, every, evals", [(10, 5, 2), (10, 1, 10), (7, 5, 2),

@@ -1,7 +1,8 @@
 """A four-chip cell's run at a size the CPU can hold, on four virtual
 devices, with the look for a chip skipped: `correct` holds for the sharded
-program as it is, and fails when the lanes of every chip but the first never
-reach the host (the exchange between chips left out).  The cell is the
+program as it is, whose compiled program the harness reads back for its
+memory, and fails when the lanes of every chip but the first never reach
+the host (the exchange between chips left out).  The cell is the
 paper's 24-lane grid (bench/workloads/paper-full-grid.json), cut in size and
 added here as a later benchmark PR would add a four-chip cell: by an entry
 and a limits file."""
@@ -44,9 +45,20 @@ SCRIPT = textwrap.dedent("""
 
     import jax
     from bench import run
-    run.check_device = lambda chips: jax.devices()[:chips]
-    run.peak_bytes = lambda devices, rec: 1
-    if fault:
+
+    class Chip:  # a CPU device with the allocator statistics the CPU lacks
+        def __init__(self, device):
+            self.device = device
+
+        def __getattr__(self, name):
+            return getattr(self.device, name)
+
+        def memory_stats(self):
+            return {"peak_bytes_in_use": 0}
+
+    run.check_device = lambda chips: [Chip(d) for d in jax.devices()[:chips]]
+    if fault:  # the planted program is a plain function, with nothing to compile
+        run.peak_bytes = lambda devices, rec: 1
         import repro.fl.engine as engine
         build = engine.ExperimentEngine._build_sharded
 
@@ -81,6 +93,9 @@ def test_the_sharded_program_as_it_is_is_correct(tmp_path):
     out = result(tmp_path, "sound")
     assert out["device"]["count"] == 4
     assert out["correct"], out["checked"]
+    # the sharded program's temporaries, read from the program compiled anew
+    # for the arguments the window passed (`Recorder.compiled`)
+    assert out["device"]["memory_peak_bytes"] > 0
 
 
 def test_lanes_that_never_leave_their_chip_are_caught(tmp_path):
